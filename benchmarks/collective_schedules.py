@@ -15,7 +15,6 @@ def run() -> list[str]:
             os.environ.get("XLA_FLAGS", ""):
         os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                                    + " --xla_force_host_platform_device_count=4")
-    from repro.launch import compat  # noqa: F401  (new-API shims, pre-jax use)
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P, AxisType

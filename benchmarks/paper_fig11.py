@@ -7,10 +7,9 @@ batched sweep engine (at most one compile), instead of 155 separate
 compile+scan invocations."""
 import time
 
-import jax
 import numpy as np
 
-from benchmarks._util import FigureRecord, perf_block, scaled, smoke_mode
+from benchmarks._util import FigureRecord, perf_block, scaled
 from repro.core.smla import engine, sweep
 from repro.core.smla.analytic import default_horizon
 from repro.core.smla.config import paper_configs
@@ -98,40 +97,6 @@ def run(n_req: int = 600, horizon: int | None = None) -> list[str]:
         "rows": table,
     }).emit()
 
-    # ---- second backend: the same grid through the fused Pallas kernel.
-    # On CPU (CI) Mosaic cannot lower, so the pass runs in interpreter
-    # mode — it validates the kernel end-to-end and records a comparable
-    # perf row, but cannot show the on-chip state-residency win, which
-    # needs a TPU (see EXPERIMENTS.md §Execution backends).  Full runs on
-    # CPU bound the interpreter pass to a sub-grid.
-    on_tpu = jax.default_backend() == "tpu"
-    pl_cells = cells if (smoke_mode() or on_tpu) else cells[:25]
-    pl_opts = SimOptions(horizon=horizon, backend="pallas",
-                         interpret=not on_tpu)
-    c0p, t0p = engine.compile_count(), time.perf_counter()
-    res_p = sweep.run_sweep(sweep.SweepSpec(tuple(pl_cells),
-                                            options=pl_opts))
-    wall_p = time.perf_counter() - t0p
-    compiles_p = engine.compile_count() - c0p
-    assert compiles_p <= len(set(res_p.chunks)), \
-        f"pallas pass took {compiles_p} compiles " \
-        f"(want <= {len(set(res_p.chunks))} chunk widths)"
-    # cross-backend fidelity on a probe cell (ints must match exactly)
-    probe = res_p.names[0]
-    assert np.array_equal(np.asarray(res[probe]["served"]),
-                          np.asarray(res_p[probe]["served"])), \
-        "pallas backend diverged from scan on served counts"
-    rec_p = FigureRecord.from_sweep(
-        "fig11.pallas", res_p, wall_p, horizon=horizon,
-        compiles=compiles_p, extra={
-            "n_req": n_req, "interpret": not on_tpu,
-            "cells_per_s_scan": perf["cells_per_s"],
-        })
-    rec_p.emit()
-    rows.append(f"# pallas backend [{'interpret' if not on_tpu else 'tpu'}]"
-                f": {len(pl_cells)} cells, {wall_p:.1f}s wall, "
-                f"{rec_p.perf['cells_per_s']:.1f} cells/s "
-                f"(scan: {perf['cells_per_s']:.1f})")
     return rows
 
 

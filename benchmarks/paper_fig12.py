@@ -24,13 +24,12 @@ SMLA = ("dedicated_slr", "cascaded_slr", "dedicated_mlr", "cascaded_mlr")
 CORES = (4, 8, 16)
 
 
-def run(n_mixes: int = 6, n_req: int = 500, horizon: int | None = None,
-        seed: int = 0) -> list[str]:
-    n_mixes = scaled(n_mixes, 2)
-    n_req = scaled(n_req, 80)
+def grid_cells(n_mixes: int = 6, n_req: int = 500, seed: int = 0):
+    """The figure's cells, 'c{cores}/m{mix}/{config}', and each mix's
+    workload names: per core count, `n_mixes` random mixes x the 5 IO
+    configurations."""
     rng = np.random.default_rng(seed)
     cfgs = paper_configs(4)
-
     cells, mixes = [], {}
     for cores in CORES:
         per_chan = max(cores // 4, 1)
@@ -42,6 +41,33 @@ def run(n_mixes: int = 6, n_req: int = 500, horizon: int | None = None,
                 cells.append(sweep.make_cell(
                     f"c{cores}/m{m}/{cname}", sc, specs, n_req,
                     seed=seed + m))
+    return cells, mixes
+
+
+def compile_bound(res: sweep.SweepResult) -> int:
+    """One shape group per core count, times the auto-chunk ladder widths
+    actually used (each cached across runs)."""
+    return len(CORES) * max(len(set(res.chunks)), 1)
+
+
+def probe_mismatches(cells, res: sweep.SweepResult,
+                     horizon: int) -> list[str]:
+    """Metrics in which the grid's first cell differs from a standalone
+    `simulate()` of it.  Chunk width is an execution detail, so every
+    metric but `chunks_run` must be equal."""
+    probe = cells[0]
+    ref = engine.simulate(probe.stack, probe.traces,
+                          SimOptions(horizon=horizon))
+    return [k for k in ref if k != "chunks_run"
+            and not np.array_equal(np.asarray(ref[k]), res[probe.name][k])]
+
+
+def run(n_mixes: int = 6, n_req: int = 500, horizon: int | None = None,
+        seed: int = 0) -> list[str]:
+    n_mixes = scaled(n_mixes, 2)
+    n_req = scaled(n_req, 80)
+    cfgs = paper_configs(4)
+    cells, mixes = grid_cells(n_mixes, n_req, seed)
     if horizon is None:
         horizon = scaled(default_horizon(cells), 6_000)
 
@@ -50,18 +76,13 @@ def run(n_mixes: int = 6, n_req: int = 500, horizon: int | None = None,
     res = sweep.run_sweep(spec)
     wall = time.perf_counter() - t0
     compiles = engine.compile_count() - c0
-    # one shape group per core count, times the auto-chunk ladder widths
-    # actually used (each cached across runs)
-    bound = len(CORES) * max(len(set(res.chunks)), 1)
+    bound = compile_bound(res)
     assert compiles <= bound, \
         f"fig12 grid took {compiles} compiles (want <= {bound})"
 
     # acceptance cross-check: one cell must equal the per-config path exactly
-    probe = cells[0]
-    ref = engine.simulate(probe.stack, probe.traces,
-                          SimOptions(horizon=horizon))
-    assert np.array_equal(np.asarray(ref["ipc"]), res[probe.name]["ipc"]), \
-        "sweep metrics diverge from per-config simulate()"
+    bad = probe_mismatches(cells, res, horizon)
+    assert not bad, f"sweep metrics diverge from per-config simulate(): {bad}"
 
     rows = ["cores,config,ws_vs_baseline,energy_vs_baseline,"
             "pd_frac,wr_share"]

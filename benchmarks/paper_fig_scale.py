@@ -7,13 +7,13 @@ Methodology — every measurement is a **fresh subprocess** timed around
 quantity that matters for million-cell campaigns is the cold-process
 sweep latency a journal resume or a fleet worker actually pays:
 
-* ``sync``        — `SweepSpec(streaming=False)`, no compilation cache:
-                    the strict prepare->execute->harvest loop paying full
-                    XLA compilation in-process (what every sweep cost
-                    before the streaming engine).
-* ``stream_cold`` — the async pipeline with a fresh persistent
-                    compilation cache (`SimOptions.compile_cache_dir`):
-                    pays compilation once and *populates* the cache.
+* ``sync``        — `SweepSpec(streaming=False)`, persistent compilation
+                    cache off: the strict prepare->execute->harvest loop
+                    paying full XLA compilation in-process (what every
+                    sweep cost before the streaming engine).
+* ``stream_cold`` — the async pipeline with an emptied persistent
+                    compilation cache (`cache_dir`, a fixed path): pays
+                    compilation once and *populates* the cache.
 * ``stream_warm`` — the pipeline against the populated cache: what every
                     subsequent process (resume, next fleet worker, next
                     grid chunk) pays.  This is the headline `ratio` row
@@ -34,9 +34,9 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import shutil
 import subprocess
 import sys
-import tempfile
 
 from benchmarks._util import emit_json, scaled, smoke_mode
 
@@ -57,8 +57,7 @@ STREAM = WorkloadSpec("stream.t", 50.0, 0.85, write_frac=1 / 3)
 cells = sweep.paper_grid(
     [(f"w{s}", [STREAM, STREAM], s) for s in range(cfg["k"])],
     layers=(2, 4), n_req=cfg["n_req"])
-opts = SimOptions(horizon=cfg["horizon"],
-                  compile_cache_dir=cfg.get("cache_dir"))
+opts = SimOptions(horizon=cfg["horizon"])
 spec = sweep.SweepSpec(tuple(cells), options=opts,
                        streaming=cfg["streaming"],
                        on_bucket=progress_printer(cfg["label"]))
@@ -106,9 +105,23 @@ print("RESULT " + json.dumps(out))
 """
 
 
-def _run_child(code: str, cfg: dict) -> dict:
+def cache_dir(k: int) -> str:
+    """The persistent compilation cache of grid size `k`'s streaming
+    runs: a fixed path inside ``JAX_COMPILATION_CACHE_DIR`` when that is
+    set, else inside the checkout's ``.jax_cache``
+    (`engine.DEFAULT_COMPILE_CACHE_DIR`)."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    base = (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or os.path.join(root, ".jax_cache"))
+    return os.path.join(base, "fig_scale", f"k{k}")
+
+
+def _run_child(code: str, cfg: dict, env: dict | None = None) -> dict:
+    # This parent never imports JAX, so the child is the one process
+    # holding the accelerator while it runs.
     r = subprocess.run([sys.executable, "-c", code, json.dumps(cfg)],
-                       capture_output=True, text=True, env=dict(os.environ))
+                       capture_output=True, text=True,
+                       env=dict(os.environ, **(env or {})))
     if r.returncode != 0:
         raise RuntimeError(f"fig_scale child failed ({cfg.get('label')}):\n"
                            f"{r.stdout}\n{r.stderr}")
@@ -118,15 +131,20 @@ def _run_child(code: str, cfg: dict) -> dict:
     raise RuntimeError(f"fig_scale child printed no RESULT:\n{r.stdout}")
 
 
-def run_size(k: int, n_req: int, horizon: int, cache_root: str) -> dict:
-    cache = os.path.join(cache_root, f"xla-cache-k{k}")
+def run_size(k: int, n_req: int, horizon: int) -> dict:
+    cache = cache_dir(k)
     base = {"k": k, "n_req": n_req, "horizon": horizon}
     sync = _run_child(_CHILD, dict(base, streaming=False,
-                                   label=f"fig_scale:sync:k{k}"))
-    cold = _run_child(_CHILD, dict(base, streaming=True, cache_dir=cache,
-                                   label=f"fig_scale:cold:k{k}"))
-    warm = _run_child(_CHILD, dict(base, streaming=True, cache_dir=cache,
-                                   label=f"fig_scale:warm:k{k}"))
+                                   label=f"fig_scale:sync:k{k}"),
+                      env={"JAX_ENABLE_COMPILATION_CACHE": "false"})
+    shutil.rmtree(cache, ignore_errors=True)        # the cold phase
+    cache_env = {"JAX_COMPILATION_CACHE_DIR": cache}
+    cold = _run_child(_CHILD, dict(base, streaming=True,
+                                   label=f"fig_scale:cold:k{k}"),
+                      env=cache_env)
+    warm = _run_child(_CHILD, dict(base, streaming=True,
+                                   label=f"fig_scale:warm:k{k}"),
+                      env=cache_env)
     checks = {m["checksum_bandwidth"] for m in (sync, cold, warm)}
     if len(checks) != 1:
         raise RuntimeError(f"fig_scale k={k}: modes disagree on the "
@@ -149,15 +167,14 @@ def main(argv=None) -> int:
     horizon = scaled(6_000, 2_000)
     sizes = SIZES_SMOKE if smoke_mode() else SIZES_FULL
     rows = []
-    with tempfile.TemporaryDirectory(prefix="fig-scale-") as cache_root:
-        for k in sizes:
-            row = run_size(k, n_req, horizon, cache_root)
-            rows.append(row)
-            print(f"n_cells={row['n_cells']:5d}  "
-                  f"sync={row['sync']['cells_per_s']:8.1f}  "
-                  f"stream_warm={row['stream_warm']['cells_per_s']:8.1f} "
-                  f"cells/s  ratio={row['ratio']:.2f}x  "
-                  f"({row['n_buckets']} buckets)", flush=True)
+    for k in sizes:
+        row = run_size(k, n_req, horizon)
+        rows.append(row)
+        print(f"n_cells={row['n_cells']:5d}  "
+              f"sync={row['sync']['cells_per_s']:8.1f}  "
+              f"stream_warm={row['stream_warm']['cells_per_s']:8.1f} "
+              f"cells/s  ratio={row['ratio']:.2f}x  "
+              f"({row['n_buckets']} buckets)", flush=True)
 
     prune = _run_child(_PRUNE_CHILD, {
         "n_cells": scaled(20_000, 10_000), "n_req": scaled(10, 6),
@@ -171,7 +188,7 @@ def main(argv=None) -> int:
         "ratio_best": max(r["ratio"] for r in rows),
         "prune": prune,
         "methodology": ("per-mode fresh subprocess timed around run_sweep; "
-                        "sync = streaming=False without compilation cache, "
+                        "sync = streaming=False, compilation cache off, "
                         "stream_warm = pipeline + populated persistent "
                         "compile cache")})
     print(f"fig_scale -> {path}")

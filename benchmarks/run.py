@@ -67,8 +67,10 @@ def main(argv=None) -> int:
     for mod in benches:
         print(f"\n===== {mod} =====", flush=True)
         t0 = time.time()
-        # --progress streams the child (per-bucket lines land live);
-        # otherwise output is captured and replayed on completion
+        # This parent imports no JAX, so each child is the one process
+        # holding the accelerator while it runs.  --progress streams the
+        # child (per-bucket lines land live); otherwise output is
+        # captured and replayed on completion
         r = subprocess.run([sys.executable, "-m", mod],
                            capture_output=not args.progress,
                            text=True, env=env)

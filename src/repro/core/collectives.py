@@ -34,8 +34,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.launch import compat as _compat  # installs new-API shims on 0.4.x
-
 
 # ----------------------------------------------------------------------------
 # ring primitives (inside shard_map; `axis` manual)
@@ -189,12 +187,6 @@ def pod_sync_wrap(grad_fn, mesh, mode: str = "cascaded", compress=None):
     dedicated fused, optionally compressed.  Single-pod meshes: identity.
     """
     if mesh is None or "pod" not in mesh.axis_names or mesh.shape["pod"] == 1:
-        return grad_fn
-    if not _compat.SUPPORTS_PARTIAL_MANUAL:
-        # 0.4.x XLA cannot partition the partial-manual region; let GSPMD
-        # insert the cross-pod reduction (the mode='auto' schedule).  The
-        # cascaded/dedicated ring algorithms themselves are still covered by
-        # the full-manual collectives tests.
         return grad_fn
 
     def wrapped(params, batch):

@@ -86,8 +86,8 @@ chunk, backend, interpret) threaded through every entry point and the
 compile cache.  ``backend="scan"`` is this module's reference pipeline;
 ``backend="pallas"`` runs the *same* ``_sim_core`` inside a Pallas kernel
 tiled over blocks of the stacked cell axis (``core/smla/pallas_engine``),
-keeping the whole per-cell state dict on-chip across the chunked
-while-loop instead of round-tripping it through HBM every fast cycle.
+meant to keep the whole per-cell state dict on-chip across the chunked
+while-loop; it runs in interpreter mode only (see that module).
 """
 from __future__ import annotations
 
@@ -119,7 +119,7 @@ AUTO = "auto"
 #: execution backends: ``"scan"`` is the reference ``lax.scan`` pipeline
 #: (state round-trips HBM every chunk); ``"pallas"`` fuses the whole
 #: chunked while-loop into a Pallas kernel over cell blocks
-#: (``core/smla/pallas_engine.py``) so per-cell state stays on-chip.
+#: (``core/smla/pallas_engine.py``), interpreter mode only.
 BACKENDS = ("scan", "pallas")
 
 
@@ -143,7 +143,8 @@ class SimOptions:
                compatible, see ``pallas_engine`` for the documented float
                tolerance).
     interpret  run the Pallas kernel in interpreter mode — required on
-               CPU (CI) where Mosaic cannot lower; ignored by ``"scan"``.
+               every platform, as the kernel does not lower through
+               Mosaic (see ``pallas_engine``); ignored by ``"scan"``.
     validate   debug mode: wrap the compiled program in
                ``jax.experimental.checkify`` NaN / negative-cycle guards
                (both backends — the checks run on the kernel's outputs).
@@ -152,14 +153,15 @@ class SimOptions:
                garbage into figures.  Off by default (one extra pass over
                the outputs; results are bit-identical either way).
     compile_cache_dir
-               directory for JAX's *persistent* compilation cache.  When
-               set, every entry point applies it before compiling, so the
-               XLA executables behind each shape group survive the
-               process: a journal resume (or any re-run of the same
-               grid) deserialises the compiled program instead of paying
-               the multi-second XLA compile again.  ``None`` (default)
-               leaves the process-global cache configuration untouched.
-               Results are bit-identical with or without the cache.
+               directory for JAX's *persistent* compilation cache.  Every
+               entry point applies the cache before compiling, so the XLA
+               executables behind each shape group survive the process: a
+               journal resume (or any re-run of the same grid)
+               deserialises the compiled program instead of paying the
+               multi-second XLA compile again.  ``JAX_COMPILATION_CACHE_DIR``
+               in the environment wins over this field; with neither set
+               the cache lives at ``DEFAULT_COMPILE_CACHE_DIR``.  Results
+               are bit-identical with or without the cache.
     """
     horizon: int
     chunk: int | None | str = AUTO
@@ -218,22 +220,41 @@ def _check_backend(options: SimOptions) -> None:
             "kernel in interpreter mode (same semantics, no fusion)")
 
 
-#: last compile_cache_dir applied to the process-global jax config — the
+#: persistent compilation cache when neither ``JAX_COMPILATION_CACHE_DIR``
+#: nor ``SimOptions.compile_cache_dir`` names one: a fixed directory in
+#: the source checkout (listed in .gitignore).  The path is part of what
+#: makes a cache hit, so it must not vary between runs.
+DEFAULT_COMPILE_CACHE_DIR = os.path.normpath(os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), *[os.pardir] * 4,
+    ".jax_cache"))
+
+#: last cache directory applied to the process-global jax config — the
 #: applier is idempotent so hot sweep loops don't re-touch jax.config.
 _CACHE_DIR_APPLIED = [None]
 
 
-def _apply_compile_cache(cache_dir: str | None) -> None:
-    """Point JAX's persistent compilation cache at `cache_dir`.
+def compile_cache_dir(options: SimOptions) -> str:
+    """The persistent-cache directory `options` runs under:
+    ``JAX_COMPILATION_CACHE_DIR`` if set, else
+    ``options.compile_cache_dir``, else `DEFAULT_COMPILE_CACHE_DIR`."""
+    return os.path.normpath(os.environ.get("JAX_COMPILATION_CACHE_DIR")
+                            or options.compile_cache_dir
+                            or DEFAULT_COMPILE_CACHE_DIR)
+
+
+def _apply_compile_cache(options: SimOptions) -> None:
+    """Point JAX's persistent compilation cache at
+    `compile_cache_dir(options)`.
 
     The thresholds are dropped to "cache everything" (min compile time 0,
     no minimum entry size): the sweep's executables are few and large, and
     a journal resume that recompiles them from scratch wastes more wall
     time than the grid itself on small-to-medium grids.  The jax config is
     process-global; this helper only touches it when the directory
-    actually changes, and `None` never un-sets a previously applied one
-    (entry points pass whatever their SimOptions carries)."""
-    if cache_dir is None or _CACHE_DIR_APPLIED[0] == cache_dir:
+    actually changes.  ``jax_enable_compilation_cache=False`` still turns
+    the cache off."""
+    cache_dir = compile_cache_dir(options)
+    if _CACHE_DIR_APPLIED[0] == cache_dir:
         return
     os.makedirs(cache_dir, exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", cache_dir)
@@ -1040,6 +1061,16 @@ def _validate_metrics(out: dict) -> None:
         checkify.check(jnp.all(out[k] >= 0), f"validate: negative {k}")
 
 
+def cell_mesh(n_dev: int) -> jax.sharding.Mesh:
+    """1-D ``cells`` mesh over the first `n_dev` devices.  The axis is
+    Auto: under an Explicit axis (``jax.make_mesh``'s default) the
+    engine's scatters would need an ``out_sharding`` on every ``.at[]``
+    update, while Auto leaves their sharding to the partitioner."""
+    return jax.make_mesh((n_dev,), ("cells",),
+                         axis_types=(jax.sharding.AxisType.Auto,),
+                         devices=jax.devices()[:n_dev])
+
+
 @functools.lru_cache(maxsize=None)
 def _compiled(options: SimOptions, core: CoreParams, banks: int,
               shapes_key: tuple, batched: bool, shard: int = 0):
@@ -1094,16 +1125,13 @@ def _compiled(options: SimOptions, core: CoreParams, banks: int,
                                core=core, banks=banks, chunk=options.chunk)
         base = jax.vmap(fn) if batched else fn
         if shard > 1:
-            from repro.launch import compat     # lazy: heavier import
-            mesh = compat.make_mesh((shard,), ("cells",),
-                                    devices=np.array(jax.devices()[:shard]))
             pspec = jax.sharding.PartitionSpec("cells")
-            # check_vma=False (check_rep on 0.4.x): the replication checker
-            # has no rule for while_loop; manual sharding is still valid —
-            # every output carries the partitioned cell axis.
-            base = compat.shard_map(base, mesh=mesh,
-                                    in_specs=(pspec, pspec),
-                                    out_specs=pspec, check_vma=False)
+            # check_vma=False: the replication checker has no rule for
+            # while_loop; manual sharding is still valid — every output
+            # carries the partitioned cell axis.
+            base = jax.shard_map(base, mesh=cell_mesh(shard),
+                                 in_specs=(pspec, pspec),
+                                 out_specs=pspec, check_vma=False)
     if not options.validate:
         return jax.jit(base)
     from jax.experimental import checkify
@@ -1142,7 +1170,7 @@ def batched_simulate(params: dict, traces: dict,
     n_cells must be divisible by n)."""
     options = _require_options(options, "batched_simulate").resolved()
     _check_backend(options)
-    _apply_compile_cache(options.compile_cache_dir)
+    _apply_compile_cache(options)
     shard = int(local_cond_devices) if int(local_cond_devices) > 1 else 0
     n_cells, n_cores, n_req_max = traces["inst"].shape
     if shard and n_cells % shard:
@@ -1162,7 +1190,7 @@ def simulate(stack: StackConfig, traces: dict, options: SimOptions,
     arrays (all jnp)."""
     options = _require_options(options, "simulate").resolved()
     _check_backend(options)
-    _apply_compile_cache(options.compile_cache_dir)
+    _apply_compile_cache(options)
     n_cores, n_req = traces["inst"].shape
     params = stack.to_params()
     params["n_req"] = np.int32(n_req)

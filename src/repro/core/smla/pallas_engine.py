@@ -38,15 +38,25 @@ degradation cross-product reuses this kernel's one compiled executable.
 outside the kernel body, so validation works identically on both
 backends without a Mosaic lowering for the check primitives.
 
-On CPU/GPU, Mosaic cannot lower this kernel: pass
-``SimOptions(interpret=True)`` (the CI path) to run it through the
-Pallas interpreter — same semantics, executed as ordinary XLA ops, so
-it validates the kernel logic but not the on-chip residency win.  On
-TPU the kernel compiles; two lowering caveats to keep in mind when
-profiling there: `jax.ops.segment_sum` inside the stages lowers to
-scatter-adds (Mosaic supports them, but they serialise), and the scalar
-argmax-based scheduler stages are VPU-bound, so the speedup comes from
-removing the HBM state round-trip, not from MXU work.
+The backend is interpret-only: pass ``SimOptions(interpret=True)`` to run
+the kernel through the Pallas interpreter — same semantics, executed as
+ordinary XLA ops, so it validates the kernel logic but not the on-chip
+residency win.  The kernel does not lower through Mosaic for the TPU.
+Compiled for a described v5e, the chip's compiler stops it at three
+places in turn:
+
+1. `spec_of` gives every 1-D per-cell param a block of
+   ``DEFAULT_BLOCK_CELLS`` (8); Mosaic wants a rank-1 block that is the
+   whole array or a multiple of 128.
+2. The chunk's ``lax.scan`` over fast cycles (`engine._sim_core`) has an
+   extensive input, and Mosaic's scan lowering does not implement it.
+3. ``jax.ops.segment_sum`` (first reached in `policies.refresh_demand`)
+   lowers to a scatter-add, which the Pallas TPU lowering does not
+   implement; `engine.py` has about two dozen more ``segment_*`` and
+   ``.at[]`` sites.
+
+Porting the kernel is worth it only if a chip measurement shows the
+fused layout can beat the scan backend.
 """
 from __future__ import annotations
 
@@ -58,7 +68,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.smla import engine
-from repro.launch import compat as _compat  # noqa: F401  (pltpu.CompilerParams alias)
 
 #: cells simulated per grid step.  Sized so a block's full state dict
 #: (queue arrays x q_size, bank matrices x R*B, per-core vectors — a few

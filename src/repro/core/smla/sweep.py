@@ -44,8 +44,8 @@ when journaling, in-memory stacked arrays otherwise), so host memory for
 a journal-backed sweep is O(bucket), not O(grid).  ``streaming=False``
 runs the identical plan strictly synchronously (prepare -> execute ->
 harvest per bucket); both modes are bit-identical — pipelining only moves
-wall-clock, never numerics.  ``SimOptions.compile_cache_dir`` adds the
-persistent JAX compilation cache on top, so the compiled shape-group
+wall-clock, never numerics.  The persistent JAX compilation cache
+(`engine.compile_cache_dir`) adds on top, so the compiled shape-group
 executables survive the *process* and a journal resume skips both
 re-execution and recompilation.
 
@@ -645,11 +645,8 @@ def _run_with_retry(fn, max_retries: int, base_s: float) -> tuple[dict, int]:
 
 def _cell_sharding(n_dev: int):
     """NamedSharding that splits a stacked batch's leading cell axis
-    across all visible devices (built through the launch.compat shims, so
-    it works on either JAX API surface)."""
-    from repro.launch import compat
-    mesh = compat.make_mesh((n_dev,), ("cells",),
-                            devices=np.array(jax.devices()))
+    across all visible devices."""
+    mesh = engine.cell_mesh(n_dev)
     return jax.sharding.NamedSharding(mesh,
                                       jax.sharding.PartitionSpec("cells"))
 

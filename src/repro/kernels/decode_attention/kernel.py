@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.launch import compat as _compat  # noqa: F401  (pltpu.CompilerParams alias)
 
 NEG_INF = -1e30
 

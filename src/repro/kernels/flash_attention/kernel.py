@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.launch import compat as _compat  # noqa: F401  (CompilerParams alias)
 
 NEG_INF = -1e30
 

@@ -31,7 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.launch import compat as _compat  # noqa: F401  (pltpu.CompilerParams alias)
 
 
 def _cascade_kernel(x_ref, w_ref, o_ref, acc, *, n_t: int):

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.launch import compat as _compat  # noqa: F401  (pltpu.CompilerParams alias)
 
 
 def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, st_ref, state, *,

@@ -9,21 +9,21 @@ see core/collectives.py), 'model' carries TP/SP/EP.
 """
 from __future__ import annotations
 
-from repro.launch import compat
-from repro.launch.compat import AxisType
+import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes,
-                            axis_types=(AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")):
     """Small mesh for multi-device CPU tests (device count permitting)."""
-    return compat.make_mesh(shape, axes,
-                            axis_types=(AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def dp_size(mesh) -> int:

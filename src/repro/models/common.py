@@ -12,9 +12,7 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-
-from repro.launch import compat
+from jax.sharding import AxisType, PartitionSpec as P
 
 Params = Any  # nested dict pytree of jnp arrays
 
@@ -147,6 +145,12 @@ def sinusoidal_positions(seq: int, dim: int, offset=0) -> jax.Array:
 # ----------------------------------------------------------------------------
 
 
+def auto_axis_names(mesh) -> set[str]:
+    """Axes of `mesh` whose type is Auto (shardable by the compiler)."""
+    return {a for a, t in zip(mesh.axis_names, mesh.axis_types)
+            if t == AxisType.Auto}
+
+
 def filter_spec(spec: P, shape: tuple[int, ...]) -> P | None:
     """Restrict a PartitionSpec to the axes of the active mesh, dropping any
     axis that is absent or does not divide the corresponding dim.
@@ -155,15 +159,14 @@ def filter_spec(spec: P, shape: tuple[int, ...]) -> P | None:
     production mesh) apply unchanged on smaller test meshes or no mesh.
     Returns None when there is no active mesh.
     """
-    am = compat.get_abstract_mesh()
-    if am is None or am.empty:
-        return None
-    if compat.suppress_sharding_constraints(am):
+    am = jax.sharding.get_abstract_mesh()
+    if am.empty:
         return None
     # Only constrain over Auto axes: inside a (partial-)manual shard_map
     # region the manual axes (e.g. 'pod' during hierarchical grad sync) must
-    # not appear in sharding constraints.
-    names = compat.auto_axis_names(am)
+    # not appear in sharding constraints — with_sharding_constraint raises
+    # on any axis that is not Auto in the current abstract mesh.
+    names = auto_axis_names(am)
     sizes = dict(am.shape)
     entries = list(spec) + [None] * (len(shape) - len(spec))
     out = []

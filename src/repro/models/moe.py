@@ -30,7 +30,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig, ParallelConfig
-from repro.launch import compat
 from repro.models import common as cm
 
 DP = ("pod", "data")
@@ -57,8 +56,8 @@ def route(x, w_router, cfg: ModelConfig):
 def moe_ffn(x, p, cfg: ModelConfig, pcfg: ParallelConfig):
     """p: {'router': (d, E), 'experts': {w_gate/w_up/w_down: (E, ...)}}."""
     top_w, top_ids, aux = route(x, p["router"], cfg)
-    am = compat.get_abstract_mesh()
-    use_sm = (pcfg.moe_impl == "shard_map" and am is not None and not am.empty
+    am = jax.sharding.get_abstract_mesh()
+    use_sm = (pcfg.moe_impl == "shard_map" and not am.empty
               and "model" in am.axis_names and am.shape["model"] > 1)
     if use_sm:
         out = _moe_shard_map(x, top_w, top_ids, p["experts"], cfg, pcfg, am)
@@ -104,7 +103,7 @@ def _moe_shard_map(x, top_w, top_ids, experts, cfg, pcfg, am):
     # only Auto axes may appear in the inner shard_map's specs: inside the
     # hierarchical-sync region 'pod' is already Manual (and the batch is
     # already pod-local), so it must be excluded here.
-    auto = compat.auto_axis_names(am)
+    auto = cm.auto_axis_names(am)
     dp = tuple(a for a in DP if a in auto)
     dp_size = int(math.prod(am.shape[a] for a in dp)) if dp else 1
     if b % dp_size != 0:

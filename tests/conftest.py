@@ -10,8 +10,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import jax
 import pytest
 
-import repro.launch.compat  # noqa: F401  (installs new-API shims on JAX 0.4.x)
-
 
 def pytest_addoption(parser):
     parser.addoption(
@@ -36,15 +34,20 @@ def rng():
     return jax.random.PRNGKey(0)
 
 
-def run_subprocess_jax(code: str, n_devices: int = 8, timeout: int = 420):
-    """Run a JAX snippet in a subprocess with forced host device count."""
+def run_subprocess_jax(code: str, n_devices: int = 8, timeout: int = 420,
+                       env_overrides: dict | None = None):
+    """Run a JAX snippet in a subprocess with forced host device count.
+    `env_overrides` sets environment variables (a None value unsets)."""
     env = dict(os.environ)
+    for k, v in (env_overrides or {}).items():
+        if v is None:
+            env.pop(k, None)
+        else:
+            env[k] = v
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + f" --xla_force_host_platform_device_count={n_devices}")
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src") \
         + os.pathsep + env.get("PYTHONPATH", "")
-    # Install the jax version-compat shims before the snippet touches jax.
-    code = "import repro.launch.compat  # noqa: F401\n" + code
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, timeout=timeout, env=env)
     assert r.returncode == 0, f"subprocess failed:\n{r.stdout}\n{r.stderr}"

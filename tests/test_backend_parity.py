@@ -19,8 +19,8 @@ construction; this module makes that a contract:
   except `chunks_run` is invariant — chunking is an execution detail,
   `chunks_run` its only observable.
 
-Runs on CPU via the Pallas interpreter (`interpret=True` — Mosaic needs
-a TPU); the same assertions hold compiled on TPU.
+Runs through the Pallas interpreter (`interpret=True`): the kernel does
+not lower through Mosaic for the TPU (see `core/smla/pallas_engine.py`).
 """
 import json
 
@@ -31,9 +31,9 @@ from repro.core.smla import engine, policies, sweep
 from repro.core.smla.config import paper_configs
 from repro.core.smla.engine import SimOptions, simulate
 from repro.core.smla.traces import WorkloadSpec, core_traces
-from test_golden import (FLOAT_METRICS, GOLDEN_PATH, INT_METRICS, RTOL,
-                         _grid_cells)
-from test_golden import HORIZON as GOLDEN_HORIZON
+from repro.core.smla import golden
+from repro.core.smla.golden import RTOL
+from test_golden import GOLDEN_PATH
 
 try:
     import hypothesis
@@ -72,7 +72,7 @@ def test_pallas_requires_interpret_off_tpu():
     pointing at interpret=True, instead of failing inside Mosaic."""
     if jax_backend_is_tpu():
         pytest.skip("compiled pallas is legitimate here")
-    cells = _grid_cells()[:1]
+    cells = golden.grid_cells()[:1]
     with pytest.raises(ValueError, match="interpret=True"):
         simulate(cells[0].stack, cells[0].traces,
                  SimOptions(horizon=HORIZON, backend="pallas"))
@@ -85,30 +85,13 @@ def jax_backend_is_tpu() -> bool:
 
 def test_pallas_matches_golden_grid():
     """The checked-in golden numbers, byte-for-byte, through the kernel."""
-    golden = json.loads(GOLDEN_PATH.read_text())["cells"]
-    opts = SimOptions(horizon=GOLDEN_HORIZON, backend="pallas",
-                      interpret=not jax_backend_is_tpu())
-    res = sweep.run_sweep(sweep.SweepSpec(tuple(_grid_cells()),
+    want = json.loads(GOLDEN_PATH.read_text())["cells"]
+    opts = SimOptions(horizon=golden.HORIZON, backend="pallas",
+                      interpret=True)
+    res = sweep.run_sweep(sweep.SweepSpec(tuple(golden.grid_cells()),
                                           options=opts))
     assert res.backend == "pallas"
-    assert sorted(res.names) == sorted(golden)
-    errors = []
-    for name in golden:
-        m, g = res[name], golden[name]
-        for k in INT_METRICS:
-            if int(np.asarray(m[k])) != g[k]:
-                errors.append(f"{name}:{k} got {int(np.asarray(m[k]))} "
-                              f"want {g[k]}")
-        if np.asarray(m["served"]).astype(int).tolist() != g["served"]:
-            errors.append(f"{name}:served")
-        for k in FLOAT_METRICS:
-            if not np.isclose(float(np.asarray(m[k])), g[k],
-                              rtol=RTOL, atol=0.0):
-                errors.append(f"{name}:{k} got {float(np.asarray(m[k]))!r} "
-                              f"want {g[k]!r}")
-        if not np.allclose(np.asarray(m["ipc"]), g["ipc"],
-                           rtol=RTOL, atol=0.0):
-            errors.append(f"{name}:ipc")
+    errors = golden.mismatches(golden.pinned_metrics(res), want)
     assert not errors, \
         "pallas backend drifted from golden:\n" + "\n".join(errors)
 
@@ -128,7 +111,7 @@ def test_pallas_sweep_matches_scan_simulate_policy_grid():
     res = sweep.run_sweep(sweep.SweepSpec(
         tuple(cells),
         options=SimOptions(horizon=HORIZON, backend="pallas",
-                           interpret=not jax_backend_is_tpu())))
+                           interpret=True)))
     compiles = engine.compile_count() - c0
     # the policy axis must not multiply pallas compiles: one shape group,
     # at most one compile per auto-chunk ladder width
@@ -170,7 +153,7 @@ def test_pallas_matches_scan_on_fault_grid():
     res = sweep.run_sweep(sweep.SweepSpec(
         tuple(cells),
         options=SimOptions(horizon=HORIZON, backend="pallas",
-                           interpret=not jax_backend_is_tpu())))
+                           interpret=True)))
     errors = []
     for cell in cells:
         ref = simulate(cell.stack, cell.traces, SimOptions(horizon=HORIZON))
@@ -184,10 +167,10 @@ def test_pallas_matches_scan_on_fault_grid():
 def test_pallas_single_cell_matches_scan():
     """Unbatched path: `simulate()` itself under both backends, equal
     chunking — every metric including `chunks_run` must agree."""
-    cells = _grid_cells()[:4]
+    cells = golden.grid_cells()[:4]
     opts_scan = SimOptions(horizon=HORIZON, chunk=256)
     opts_pl = SimOptions(horizon=HORIZON, chunk=256, backend="pallas",
-                         interpret=not jax_backend_is_tpu())
+                         interpret=True)
     errors = []
     for cell in cells:
         ref = simulate(cell.stack, cell.traces, opts_scan)
@@ -222,6 +205,6 @@ if HAVE_HYPOTHESIS:
         got = simulate(stack, traces,
                        SimOptions(horizon=HORIZON, chunk=256,
                                   backend="pallas",
-                                  interpret=not jax_backend_is_tpu()))
+                                  interpret=True))
         errors = _diff_metrics(f"{config}", got, ref, skip=("chunks_run",))
         assert not errors, "\n".join(errors)

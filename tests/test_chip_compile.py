@@ -1,0 +1,89 @@
+"""Compile the batched scan engine for a described TPU v5e chip.
+
+No chip is attached: `jax.experimental.topologies` describes a v5e, and
+XLA's TPU compiler builds the program for it.  That refuses what the chip
+would refuse (an op it cannot lower, a program too large for its memory)
+at no chip time.  Nothing runs, so this says nothing about results or
+times.
+
+The shapes are real buckets of the paper's sweeps at full size: one
+Fig. 11 bucket (20 single-core cells, ``n_req=600``) and one 16-core
+Fig. 12 bucket (4 cells of 4 cores, ``n_req=500``), each at the horizon
+its figure derives.
+
+The topology is described only inside the fixture below: loading the TPU
+library while a module is imported would make every test worker try to
+take it.  The persistent compile cache is off around these compiles, as
+an executable built for a described chip cannot be read back without one.
+"""
+import jax
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.smla import engine, sweep
+from repro.core.smla.analytic import default_horizon
+from repro.core.smla.engine import SimOptions
+from repro.core.smla.traces import WORKLOADS
+
+#: HBM of one TPU v5e chip (Google Cloud documentation, "TPU v5e")
+V5E_HBM_BYTES = 16e9
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture
+def no_persistent_cache():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+
+
+def _fig11_cells():
+    return sweep.paper_grid([(w.name, [w], 0) for w in WORKLOADS],
+                            layers=(4,), n_req=600)
+
+
+def _fig12_c16_cells():
+    from benchmarks import paper_fig12
+    cells, _ = paper_fig12.grid_cells()
+    return [c for c in cells if c.name.startswith("c16/")]
+
+
+@pytest.mark.parametrize("cells_of", [_fig11_cells, _fig12_c16_cells],
+                         ids=["fig11", "fig12_c16"])
+def test_scan_bucket_compiles_for_v5e(topo, no_persistent_cache, cells_of):
+    cells = cells_of()
+    opts = SimOptions(horizon=default_horizon(cells))
+    spec = sweep.SweepSpec(tuple(cells), options=opts)
+    bkt = sweep._plan(spec, opts, cells, 1)[0]
+    params, traces = sweep._build_arrays(bkt)
+    n_cells, n_cores, n_req_max = traces["inst"].shape
+    fn = engine._compiled(opts.with_chunk(bkt.chunk_b), spec.core,
+                          bkt.banks,
+                          (n_cells, n_cores, n_req_max, bkt.r_max), True)
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    shape_of = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
+        np.shape(a), np.asarray(a).dtype, sharding=one_chip)
+    compiled = fn.lower(jax.tree_util.tree_map(shape_of, params),
+                        jax.tree_util.tree_map(shape_of, traces)).compile()
+
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes + mem.generated_code_size_in_bytes)
+    assert 0 < used < V5E_HBM_BYTES, mem

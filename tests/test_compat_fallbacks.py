@@ -1,26 +1,19 @@
-"""Re-test the two jax 0.4.x fallbacks guarded by `launch/compat.py`.
+"""The two manual-axis behaviours the model code relies on.
 
-Each guard exists because a specific operation breaks on the pinned
-jax/jaxlib 0.4.x (container: 0.4.37).  These tests re-run the *actual
-breaking operation* in a subprocess (forced 8-device host platform) and
-assert the observed capability matches the guard:
+Each probe runs in a subprocess (forced 8-device host platform):
 
-* `compat.SUPPORTS_PARTIAL_MANUAL` — partial-manual shard_map (manual
-  'pod', auto rest) with `lax.axis_index` in the body lowers to an XLA
-  PartitionId instruction 0.4.x SPMD cannot partition.  Guards
-  `core/collectives.py::pod_sync_wrap`'s hierarchical grad sync.
-* `compat.suppress_sharding_constraints` — `with_sharding_constraint`
-  naming mesh axes inside a manual shard_map region raises
-  ``Axis ... is also found in manual_axes`` at trace time on 0.4.x.
-  Guards `models/common.py::filter_spec`.
+* partial-manual shard_map (manual 'pod', Auto rest) with `lax.axis_index`
+  and a 'pod' collective in the body compiles and runs.
+  `core/collectives.py::pod_sync_wrap`'s hierarchical grad sync is built
+  on it.
+* `with_sharding_constraint` accepts only axes that are Auto in the
+  current abstract mesh, so naming a manual axis raises.
+  `models/common.py::filter_spec` therefore drops every axis that is not
+  Auto, and `common.shard` stays safe inside (partial-)manual regions.
 
-If a jax upgrade fixes the underlying operation while the guard still
-reports it broken (or vice versa), the matching test FAILS — that is the
-signal to delete the fallback (plus this test) rather than carry a dead
-shim forward.  Probes print a verdict line instead of crashing, so the
-subprocess exits 0 either way and the assertion happens here.
+Probes print verdict lines instead of crashing, so the subprocess exits 0
+either way and the assertions happen here.
 """
-from repro.launch import compat
 
 
 def _probe(code: str) -> str:
@@ -33,9 +26,9 @@ def _probe(code: str) -> str:
 PARTIAL_MANUAL_PROBE = """
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-import repro.launch.compat as compat
+from repro.launch.mesh import make_test_mesh
 
-mesh = compat.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_test_mesh((2, 2, 2), ("pod", "data", "model"))
 
 def body(x):
     y = x * 2 + jax.lax.axis_index("pod")
@@ -43,59 +36,69 @@ def body(x):
 
 x = jnp.arange(32.0).reshape(8, 4)
 try:
-    f = compat.shard_map(body, mesh=mesh, in_specs=P("pod"), out_specs=P(),
-                         axis_names={"pod"}, check_vma=False)
-    jax.block_until_ready(jax.jit(f)(x))
-    print("VERDICT: OK")
+    f = jax.shard_map(body, mesh=mesh, in_specs=P("pod"), out_specs=P(),
+                      axis_names={"pod"}, check_vma=False)
+    out = jax.block_until_ready(jax.jit(f)(x))
+    print("VERDICT: OK", float(out.sum()))
 except Exception as e:
-    print("VERDICT: FAIL", type(e).__name__)
+    print("VERDICT: FAIL", type(e).__name__, e)
 """
 
 WSC_MANUAL_PROBE = """
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-import repro.launch.compat as compat
+from repro.launch.mesh import make_test_mesh
+from repro.models import common as cm
 
-mesh = compat.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_test_mesh((2, 2, 2), ("pod", "data", "model"))
 
-def body(x):
-    # trace-time: is the guard active inside the manual region?
-    print("GUARD:", compat.suppress_sharding_constraints(mesh))
+def full_manual(x):
+    print("FULL-SPEC:", cm.filter_spec(P("data"), x.shape))
+    return cm.shard(x * 2, P("data"))
+
+def partial_manual(x):
+    print("PARTIAL-SPEC:", cm.filter_spec(P(("pod", "data")), x.shape))
+    return cm.shard(x * 2, P(("pod", "data")))
+
+def raw(x):
     return jax.lax.with_sharding_constraint(x * 2, P("data"))
 
 x = jnp.arange(64.0).reshape(8, 8)
-try:
-    with compat.set_mesh(mesh):
-        f = compat.shard_map(body, mesh=mesh, in_specs=P("pod"),
-                             out_specs=P("pod"), check_vma=False)
-        jax.block_until_ready(jax.jit(f)(x))
-    print("VERDICT: OK")
-except Exception as e:
-    print("VERDICT: FAIL", type(e).__name__)
+with jax.set_mesh(mesh):
+    for name, fn, axes in (("full", full_manual, None),
+                           ("partial", partial_manual, {"pod"}),
+                           ("raw", raw, None)):
+        kw = {} if axes is None else {"axis_names": axes}
+        try:
+            f = jax.shard_map(fn, mesh=mesh, in_specs=P("pod"),
+                              out_specs=P("pod"), check_vma=False, **kw)
+            out = jax.block_until_ready(jax.jit(f)(x))
+            ok = bool((out == 2 * x).all())
+            print(f"VERDICT {name}: {'OK' if ok else 'WRONG'}")
+        except Exception as e:
+            print(f"VERDICT {name}: FAIL", type(e).__name__)
 """
 
 
 def test_partial_manual_guard_matches_jax():
     out = _probe(PARTIAL_MANUAL_PROBE)
-    works = "VERDICT: OK" in out
-    assert works == compat.SUPPORTS_PARTIAL_MANUAL, (
-        f"partial-manual shard_map probe says works={works} but "
-        f"compat.SUPPORTS_PARTIAL_MANUAL={compat.SUPPORTS_PARTIAL_MANUAL} "
-        f"— the 0.4.x fallback in core/collectives.pod_sync_wrap is "
-        f"{'now removable' if works else 'guarding the wrong case'}; "
-        f"update launch/compat.py.  Probe output:\n{out}")
+    # pmean over 'pod' of (2x + pod index): the pod-1 half adds 1 to each
+    # of the 16 elements of its shard, averaged with pod 0's +0
+    want = float(2 * sum(range(32)) / 2 + 16 * 0.5)
+    assert f"VERDICT: OK {want}" in out, (
+        f"partial-manual shard_map (manual 'pod') no longer runs; "
+        f"core/collectives.pod_sync_wrap depends on it.  Probe output:\n"
+        f"{out}")
 
 
 def test_sharding_constraint_guard_matches_jax():
     out = _probe(WSC_MANUAL_PROBE)
-    works = "VERDICT: OK" in out
-    guard_active = "GUARD: True" in out
-    # The guard must be active exactly where the operation breaks: if the
-    # constraint now traces fine while the guard still suppresses (or it
-    # breaks while the guard waves it through), the shim is stale.
-    assert works == (not guard_active), (
-        f"with_sharding_constraint-in-manual-region probe says "
-        f"works={works} but suppress_sharding_constraints={guard_active} "
-        f"— the 0.4.x fallback in models/common.filter_spec is "
-        f"{'now removable' if works else 'not suppressing where needed'}; "
-        f"update launch/compat.py.  Probe output:\n{out}")
+    # a raw constraint naming a manual axis is refused, which is why
+    # filter_spec keeps only Auto axes ...
+    assert "VERDICT raw: FAIL" in out, out
+    # ... so inside a fully-manual region no axis survives, and inside a
+    # partial-manual one only the Auto axes do
+    assert "FULL-SPEC: PartitionSpec(None, None)" in out, out
+    assert "PARTIAL-SPEC: PartitionSpec('data', None)" in out, out
+    assert "VERDICT full: OK" in out, out
+    assert "VERDICT partial: OK" in out, out

@@ -1,12 +1,10 @@
 """Golden regression harness for the SMLA cycle engine.
 
-Pins every scalar metric (plus per-core served/ipc) of a tiny
-2-workload x 5-config x {2,4}-layer sweep — with writes, fast refresh, and
-power-down all exercised — to checked-in values, so silent numeric drift in
-the engine fails CI with a per-cell, per-metric diff.
-
-Integer metrics must match exactly; floats to 1e-6 rtol (engine arithmetic
-is deterministic, but float reductions may reassociate across platforms).
+Pins every scalar metric (plus per-core served/ipc) of the tiny grid in
+`repro.core.smla.golden` — 2 workloads x 5 configs x {2,4} layers, with
+writes, fast refresh, and power-down all exercised — to checked-in values,
+so silent numeric drift in the engine fails CI with a per-cell, per-metric
+diff.  Integer metrics must match exactly; floats to `golden.RTOL`.
 
 Regenerate after an *intentional* engine change with:
 
@@ -16,68 +14,28 @@ and commit the new `tests/golden/smla_small_grid.json` alongside the
 engine change that explains it.  (No hypothesis dependency — this module
 must run in a bare environment.)
 """
-import dataclasses
 import json
 import pathlib
 
-import numpy as np
 import pytest
 
-from repro.core.smla import engine, sweep
+from repro.core.smla import engine, golden, sweep
 from repro.core.smla.config import paper_configs
-from repro.core.smla.traces import WORKLOADS
+from repro.core.smla.engine import SimOptions
 
 GOLDEN_PATH = pathlib.Path(__file__).parent / "golden" / "smla_small_grid.json"
 
-HORIZON = 4_000
-N_REQ = 80
-SEED = 13
-#: one low-intensity read-heavy and one high-intensity write-heavy workload
-GRID_WORKLOADS = (WORKLOADS[4], WORKLOADS[26])      # low.05, stream.1
-
-INT_METRICS = ("n_act", "n_row_conflicts", "n_wr", "bus_cycles",
-               "wr_bus_cycles", "refresh_cycles", "pd_cycles", "n_grants",
-               "n_slot_grants", "n_enqueued", "n_outstanding",
-               # refresh/power subsystem counters — identically zero under
-               # the default policy, pinned so the golden grid also guards
-               # the new machinery's bit-identity when disabled
-               "ref_postponed", "ref_pulled_in", "ref_debt_max",
-               "ref_debt_end", "sr_cycles", "n_sr_exit")
-FLOAT_METRICS = ("bandwidth_gbps", "bus_util", "pd_frac", "sr_frac",
-                 "makespan_ns", "horizon_ns")
-RTOL = 1e-6
-
-
-def _grid_cells():
-    cells = []
-    for layers in (2, 4):
-        for cname, sc in paper_configs(layers).items():
-            # fast refresh so tREFI/tRFC paths are pinned inside the tiny
-            # horizon; everything else is the stock configuration
-            sc = dataclasses.replace(sc, t_refi_ns=1200.0)
-            for w in GRID_WORKLOADS:
-                cells.append(sweep.make_cell(
-                    f"L{layers}/{cname}/{w.name}", sc, [w, w], N_REQ,
-                    seed=SEED))
-    return cells
-
 
 def _run_grid() -> dict:
-    cells = _grid_cells()
+    cells = golden.grid_cells()
     c0 = engine.compile_count()
-    res = sweep.run_sweep(sweep.SweepSpec(tuple(cells), HORIZON))
+    res = sweep.run_sweep(sweep.SweepSpec(
+        tuple(cells), options=SimOptions(horizon=golden.HORIZON)))
     compiles = engine.compile_count() - c0
     assert compiles <= len(set(res.chunks)), \
         f"golden grid is one static shape group (x auto-chunk widths), " \
         f"took {compiles} compiles"
-    out = {}
-    for name, m in zip(res.names, res.cells):
-        cell = {k: int(np.asarray(m[k])) for k in INT_METRICS}
-        cell.update({k: float(np.asarray(m[k])) for k in FLOAT_METRICS})
-        cell["served"] = np.asarray(m["served"]).astype(int).tolist()
-        cell["ipc"] = np.asarray(m["ipc"]).astype(float).tolist()
-        out[name] = cell
-    return out
+    return golden.pinned_metrics(res)
 
 
 def test_golden_small_grid(request):
@@ -85,8 +43,9 @@ def test_golden_small_grid(request):
     if request.config.getoption("--update-golden"):
         GOLDEN_PATH.parent.mkdir(parents=True, exist_ok=True)
         payload = {
-            "meta": {"horizon": HORIZON, "n_req": N_REQ, "seed": SEED,
-                     "workloads": [w.name for w in GRID_WORKLOADS],
+            "meta": {"horizon": golden.HORIZON, "n_req": golden.N_REQ,
+                     "seed": golden.SEED,
+                     "workloads": [w.name for w in golden.GRID_WORKLOADS],
                      "note": "regenerate: PYTHONPATH=src python -m pytest "
                              "tests/test_golden.py --update-golden"},
             "cells": got,
@@ -97,41 +56,27 @@ def test_golden_small_grid(request):
 
     assert GOLDEN_PATH.exists(), \
         "golden file missing — run pytest tests/test_golden.py --update-golden"
-    golden = json.loads(GOLDEN_PATH.read_text())["cells"]
-    assert sorted(got) == sorted(golden), "grid cell set changed"
-    errors = []
-    for name, g in golden.items():
-        m = got[name]
-        for k in INT_METRICS:
-            if m[k] != g[k]:
-                errors.append(f"{name}:{k} got {m[k]} want {g[k]}")
-        if m["served"] != g["served"]:
-            errors.append(f"{name}:served got {m['served']} "
-                          f"want {g['served']}")
-        for k in FLOAT_METRICS:
-            if not np.isclose(m[k], g[k], rtol=RTOL, atol=0.0):
-                errors.append(f"{name}:{k} got {m[k]!r} want {g[k]!r}")
-        if not np.allclose(m["ipc"], g["ipc"], rtol=RTOL, atol=0.0):
-            errors.append(f"{name}:ipc got {m['ipc']} want {g['ipc']}")
+    want = json.loads(GOLDEN_PATH.read_text())["cells"]
+    errors = golden.mismatches(got, want)
     assert not errors, "engine drifted from golden:\n" + "\n".join(errors)
 
 
 def test_golden_exercises_new_machinery():
     """The pinned grid must actually cover writes, refresh, and power-down,
     otherwise the golden file can't protect those paths."""
-    golden = json.loads(GOLDEN_PATH.read_text())["cells"]
-    assert any(c["n_wr"] > 0 for c in golden.values())
-    assert any(c["refresh_cycles"] > 0 for c in golden.values())
-    assert any(c["pd_cycles"] > 0 for c in golden.values())
-    slotted = [c for n, c in golden.items() if "cascaded_slr" in n]
+    cells = json.loads(GOLDEN_PATH.read_text())["cells"]
+    assert any(c["n_wr"] > 0 for c in cells.values())
+    assert any(c["refresh_cycles"] > 0 for c in cells.values())
+    assert any(c["pd_cycles"] > 0 for c in cells.values())
+    slotted = [c for n, c in cells.items() if "cascaded_slr" in n]
     assert slotted and all(c["n_slot_grants"] == c["n_grants"]
                            for c in slotted)
     # the default-policy grid must pin the refresh/power machinery OFF
     assert all(c["sr_cycles"] == 0 and c["ref_debt_max"] == 0
-               for c in golden.values())
+               for c in cells.values())
     # the refresh accounting fix, pinned at grid level: per-cycle accrual
     # never exceeds one count per rank per makespan cycle
-    for name, c in golden.items():
+    for name, c in cells.items():
         layers_s, cname = name.split("/")[:2]
         sc = paper_configs(int(layers_s[1:]))[cname]
         mk_cyc = c["makespan_ns"] / sc.unit_ns

@@ -9,7 +9,7 @@ import hypothesis.strategies as st
 from repro.core.smla import energy as E
 from repro.core.smla.analytic import compare_configs, table2, weighted_speedup
 from repro.core.smla.config import IOModel, RankOrg, StackConfig, paper_configs
-from repro.core.smla.engine import CoreParams, simulate
+from repro.core.smla.engine import CoreParams, SimOptions, simulate
 from repro.core.smla.traces import WORKLOADS, WorkloadSpec, core_traces
 
 hypothesis.settings.register_profile("sim", max_examples=8, deadline=None)
@@ -73,7 +73,7 @@ def test_table1_energy_model():
 def _run(stack, specs, n_req=300, horizon=30_000, seed=0):
     traces = core_traces(seed, specs, n_req, stack.n_ranks,
                          stack.banks_per_rank)
-    return simulate(stack, traces, horizon), traces
+    return simulate(stack, traces, SimOptions(horizon=horizon)), traces
 
 
 @hypothesis.given(mpki=st.sampled_from([2.0, 10.0, 40.0]),

@@ -208,17 +208,59 @@ print("CACHE-RUN-OK")
 """
     run_a = code.replace("np.savez({}", f"np.savez({out_a!r}")
     run_b = code.replace("np.savez({}", f"np.savez({out_b!r}")
-    out = run_subprocess_jax(run_a, n_devices=1)
+    no_env = {"JAX_COMPILATION_CACHE_DIR": None}
+    out = run_subprocess_jax(run_a, n_devices=1, env_overrides=no_env)
     assert "CACHE-RUN-OK" in out
     entries = set(os.listdir(cache))
     assert entries, "first run must populate the compilation cache"
-    out = run_subprocess_jax(run_b, n_devices=1)
+    out = run_subprocess_jax(run_b, n_devices=1, env_overrides=no_env)
     assert "CACHE-RUN-OK" in out
     assert set(os.listdir(cache)) == entries     # all hits, no new compiles
     with np.load(out_a) as za, np.load(out_b) as zb:
         assert set(za.files) == set(zb.files)
         for k in za.files:
             assert np.array_equal(za[k], zb[k]), k
+
+
+def test_compile_cache_dir_precedence(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins over SimOptions.compile_cache_dir,
+    which wins over the fixed in-checkout default."""
+    own = str(tmp_path / "own")
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert engine.compile_cache_dir(SimOptions(horizon=HORIZON)) \
+        == engine.DEFAULT_COMPILE_CACHE_DIR
+    assert engine.compile_cache_dir(
+        SimOptions(horizon=HORIZON, compile_cache_dir=own)) == own
+    outside = str(tmp_path / "outside")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", outside)
+    assert engine.compile_cache_dir(
+        SimOptions(horizon=HORIZON, compile_cache_dir=own)) == outside
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert engine.DEFAULT_COMPILE_CACHE_DIR == os.path.join(root,
+                                                            ".jax_cache")
+
+
+def test_env_compile_cache_dir_is_the_only_one_written(tmp_path):
+    """A run under JAX_COMPILATION_CACHE_DIR writes cache entries there
+    and creates no other cache directory, even when SimOptions names
+    one."""
+    outside, own = tmp_path / "outside", tmp_path / "own"
+    code = f"""
+from repro.core.smla import sweep
+from repro.core.smla.engine import SimOptions
+from repro.core.smla.traces import WorkloadSpec
+
+w = WorkloadSpec("stream.t", 50.0, 0.85)
+cells = tuple(sweep.paper_grid([("s", [w], 3)], n_req=20))
+sweep.run_sweep(sweep.SweepSpec(cells, options=SimOptions(
+    horizon=2000, compile_cache_dir={str(own)!r})))
+print("ENV-CACHE-OK")
+"""
+    out = run_subprocess_jax(code, n_devices=1, env_overrides={
+        "JAX_COMPILATION_CACHE_DIR": str(outside)})
+    assert "ENV-CACHE-OK" in out
+    assert os.listdir(outside), "the named cache must hold the executables"
+    assert not own.exists()
 
 
 # ----------------------------------------------------------------------------
