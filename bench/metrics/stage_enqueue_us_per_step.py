@@ -1,0 +1,9 @@
+"""Device microseconds per fast cycle stepped in stage `_stage_enqueue`
+(`smla.enqueue`), from the stage probe's op-level trace of one chunk per
+executable (``bench/lib/probe.py``)."""
+from bench.lib import probe
+
+
+def read(run):
+    p = probe.of(run)
+    return None if p is None else p.stage("enqueue")[1]

@@ -68,9 +68,7 @@ def perf_block(wall_s: float, res, horizon: int) -> dict:
     """Machine-readable perf summary for one figure's sweep, so early-exit
     gains are comparable across commits.
 
-    res: a `SweepResult`.  Reports wall time, throughput (cells/s and
-    simulated fast-cycles/s, where a cell's simulated cycles are the
-    chunks it actually ran times its bucket's chunk width), how much of
+    res: a `SweepResult`.  Reports wall time, cells/s, how much of
     the horizon the early exit saved (`chunks_run_total` vs
     `chunks_possible`, both respecting per-bucket adaptive widths —
     `cell_n_chunks_max` is per cell), and the estimate calibration: per
@@ -83,7 +81,6 @@ def perf_block(wall_s: float, res, horizon: int) -> dict:
                       else [engine.effective_chunk(horizon, None)]
                       * len(chunks))
     n_max = np.array([engine.n_chunks(horizon, int(w)) for w in widths])
-    sim_cycles = int(np.minimum(chunks * widths, horizon).sum())
     possible = int(n_max.sum())
     wall = max(wall_s, 1e-9)
     calibration = [
@@ -97,9 +94,6 @@ def perf_block(wall_s: float, res, horizon: int) -> dict:
         "wall_s": round(wall_s, 3),
         "cells_per_s": round(len(chunks) / wall, 3),
         "n_buckets": len(res.buckets),
-        "buckets_per_s": round(len(res.buckets) / wall, 3),
-        "sim_fast_cycles": sim_cycles,
-        "sim_fast_cycles_per_s": round(sim_cycles / wall, 1),
         "horizon": horizon,
         "chunk_widths": sorted({int(w) for w in widths}),
         "cell_n_chunks_max": [int(x) for x in n_max],

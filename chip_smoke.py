@@ -25,8 +25,9 @@ unsharded on device 0, every metric but ``chunks_run`` equal.
 
 Every sweep runs with ``on_error="raise"`` and no retries, so a compiler
 or runtime error stops the script at once.  Lines before the last are
-notes (device kind, cells, compiles, wall time with the first bucket,
-which compiles, apart from the rest).  The last line of stdout is one JSON
+notes (device kind, cells, executables, how many of them XLA compiled
+or loaded from the persistent cache and the seconds each took, wall
+time with the first bucket, which builds them, apart from the rest).  The last line of stdout is one JSON
 object, ``{"ok": true, "device": {"platform", "kind", "count"}}``, printed
 only when every phase passed.  The script writes into no tracked file;
 JAX's compile cache goes where ``engine.compile_cache_dir`` says.
@@ -88,18 +89,23 @@ def run_sweep_timed(label: str, cells, opts: SimOptions,
         tuple(cells), options=opts, on_error="raise", max_retries=0,
         on_bucket=lambda done, total, wall_s, cps: marks.append(wall_s),
         **spec_kw)
-    c0, t0 = engine.compile_count(), time.perf_counter()
+    s0, t0 = engine.compile_stats(), time.perf_counter()
     res = sweep.run_sweep(spec)
     wall = time.perf_counter() - t0
-    compiles = engine.compile_count() - c0
+    s1 = engine.compile_stats()
+    compiles = s1.lru_misses - s0.lru_misses
     first = marks[0] if marks else wall
     # fast cycles the device stepped: each bucket runs until its slowest
     # cell exits, whole chunks at a time
     cycles = sum(b["chunks_run"] * b["chunk"] for b in res.buckets)
     _note(f"[{label}] {len(res.names)} cells, {len(res.buckets)} buckets, "
-          f"{compiles} compiles, horizon {opts.horizon}, {cycles} bucket "
-          f"cycles, wall {wall:.1f}s (first bucket {first:.1f}s, the rest "
-          f"{wall - first:.1f}s)")
+          f"{compiles} compiles ({s1.xla_compiles - s0.xla_compiles} by "
+          f"XLA {s1.compile_s - s0.compile_s:.1f}s, "
+          f"{s1.cache_loads - s0.cache_loads} cache loads "
+          f"{s1.load_s - s0.load_s:.1f}s, tracing and lowering "
+          f"{s1.trace_lower_s - s0.trace_lower_s:.1f}s), horizon "
+          f"{opts.horizon}, {cycles} bucket cycles, wall {wall:.1f}s "
+          f"(first bucket {first:.1f}s, the rest {wall - first:.1f}s)")
     _check(not res.failed_buckets,
            f"{label}: failed buckets {res.failed_buckets}")
     _check(len(res.names) == len(cells),

@@ -61,9 +61,10 @@ of the policy cross-product with the same padded shapes, and
 cell axis.  With default policies the pipeline is bit-identical to the
 historical monolithic step — pinned by ``tests/golden/smla_small_grid.
 json``.  Compiled executables are cached per static signature;
-``compile_count()`` exposes the number of distinct compiles and
-``reset_compile_count()`` rebases it (tests assert deltas, never
-absolutes).
+``compile_count()`` exposes the number of distinct executables,
+``compile_stats()`` how many of them XLA compiled or loaded from the
+persistent cache and the seconds each took, and ``reset_compile_count()``
+rebases both (tests assert deltas, never absolutes).
 
 Execution is *chunked*: instead of one fixed `lax.scan` over the full
 horizon, a `lax.while_loop` runs fixed-width scan chunks (``chunk`` fast
@@ -91,9 +92,11 @@ while-loop; it runs in interpreter mode only (see that module).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import os
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -740,6 +743,27 @@ def _stage_power(st, aux, t, ctx):
 _STAGES = (_stage_refresh, _stage_enqueue, _stage_schedule,
            _stage_transfer, _stage_retire, _stage_progress, _stage_power)
 
+#: prefix of every name scope `_sim_core` puts on its ops
+SCOPE_PREFIX = "smla."
+#: scope of the live-step gate, which keeps a step past the horizon from
+#: changing the state
+GATE_SCOPE = "gate"
+#: the attributable name scopes of one fast cycle, each under
+#: `SCOPE_PREFIX`: the seven stages (a stage's function name without
+#: ``_stage_``) and the gate.  A profiler trace of the device reduces by
+#: these names (``bench/lib/probe.py``).
+STAGE_SCOPES = tuple(f.__name__.removeprefix("_stage_")
+                     for f in _STAGES) + (GATE_SCOPE,)
+#: scope of the chunk loop's own control (its condition and the chunk's
+#: cycle numbers): not a stage, so a reduction counts it as unscoped
+LOOP_SCOPE = "loop"
+
+
+def _scope(name: str):
+    """`jax.named_scope` of one of `_sim_core`'s parts: trace-time
+    metadata on the ops, with no effect on the compiled program."""
+    return jax.named_scope(SCOPE_PREFIX + name)
+
 
 def _sim_core(params: dict, traces: dict, horizon: int, core: CoreParams,
               banks: int, chunk: int | None = None) -> dict:
@@ -821,7 +845,8 @@ def _sim_core(params: dict, traces: dict, horizon: int, core: CoreParams,
         t = t.astype(jnp.int32)
         aux = {"work_left": (st["served"] < n_req).any()}
         for stage in _STAGES:
-            st, aux = stage(st, aux, t, ctx)
+            with _scope(stage.__name__.removeprefix("_stage_")):
+                st, aux = stage(st, aux, t, ctx)
         return st, None
 
     i32 = jnp.int32
@@ -881,9 +906,10 @@ def _sim_core(params: dict, traces: dict, horizon: int, core: CoreParams,
         # step() writes into its argument dict, so hand it a shallow copy
         # to keep `s` as the pre-step state the gate can fall back to.
         new_s, _ = step(dict(s), t)
-        live = t < horizon
-        return jax.tree_util.tree_map(
-            lambda n, o: jnp.where(live, n, o), new_s, s), None
+        with _scope(GATE_SCOPE):
+            live = t < horizon
+            return jax.tree_util.tree_map(
+                lambda n, o: jnp.where(live, n, o), new_s, s), None
 
     def loop_cond(carry):
         s, k = carry
@@ -893,12 +919,14 @@ def _sim_core(params: dict, traces: dict, horizon: int, core: CoreParams,
         # testable invariant under any chunk width.  Debt is identically
         # zero under the default (strict) policy — the condition then
         # reduces to the historical work-only predicate bit-for-bit.
-        return (k < k_max) & ((s["served"] < n_req).any()
-                              | (s["ref_debt"] > 0).any())
+        with _scope(LOOP_SCOPE):
+            return (k < k_max) & ((s["served"] < n_req).any()
+                                  | (s["ref_debt"] > 0).any())
 
     def loop_body(carry):
         s, k = carry
-        ts = k * chunk_c + jnp.arange(chunk_c, dtype=jnp.int32)
+        with _scope(LOOP_SCOPE):
+            ts = k * chunk_c + jnp.arange(chunk_c, dtype=jnp.int32)
         s, _ = jax.lax.scan(gated_step, s, ts)
         return s, k + 1
 
@@ -988,15 +1016,109 @@ _NEVER_DEFAULTS = ("t_pd", "t_sr", "ecc_every")
 
 
 def compile_count() -> int:
-    """Distinct jitted executables built so far (sweep + single-config)."""
+    """Misses of the executable cache (`_compiled`) so far: one per
+    static signature, whether XLA then compiled it or loaded it from the
+    persistent cache.  `compile_stats` tells the two apart."""
     return _COMPILE_COUNT[0]
 
 
 def reset_compile_count() -> None:
-    """Rebase the compile counter (the executable cache itself is kept, so
-    this never *causes* recompiles).  Tests assert on deltas around this —
-    the process-global absolute value is order-dependent across tests."""
+    """Rebase the compile counter and `compile_stats` (the executable
+    cache itself is kept, so this never *causes* recompiles).  Tests
+    assert on deltas around this — the process-global absolute value is
+    order-dependent across tests."""
     _COMPILE_COUNT[0] = 0
+    for k in _XLA_STATS:
+        _XLA_STATS[k] = 0
+
+
+@dataclasses.dataclass(frozen=True)
+class CompileStats:
+    """What building the engine's executables cost, since the last
+    `reset_compile_count` (``compile_stats()``)."""
+    #: misses of the executable cache, as `compile_count`
+    lru_misses: int
+    #: executables XLA compiled (persistent-cache misses, or no cache)
+    xla_compiles: int
+    #: executables loaded from the persistent compilation cache
+    cache_loads: int
+    #: seconds in XLA compiles, and in persistent-cache loads
+    compile_s: float
+    load_s: float
+    #: seconds tracing the engine to a jaxpr and lowering it to MLIR
+    trace_lower_s: float
+
+
+#: the `jax.monitoring` events `compile_stats` reads
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_TRACE_LOWER = ("/jax/core/compile/jaxpr_trace_duration",
+                "/jax/core/compile/jaxpr_to_mlir_module_duration")
+#: `CompileStats` fields past `lru_misses`, since the last reset
+_XLA_STATS = dict(xla_compiles=0, cache_loads=0, compile_s=0.0,
+                  load_s=0.0, trace_lower_s=0.0)
+_XLA_LISTENING = [False]
+#: per thread: whether it is inside a call of an engine executable
+#: (`_engine_call`), and whether the backend compile in progress there
+#: loaded from the persistent cache
+_XLA_CURRENT = threading.local()
+
+
+@contextlib.contextmanager
+def _engine_call():
+    """Count this thread's compile events as the engine's while the
+    block runs: the call of an executable `_compiled` returned, which
+    traces, lowers and compiles (or loads) that executable on its first
+    call and nothing else.  JAX's events cannot say so themselves: a
+    cache hit names no function, and the unbatched path's is unnamed."""
+    _XLA_CURRENT.engine = True
+    try:
+        yield
+    finally:
+        _XLA_CURRENT.engine = False
+
+
+def _on_compile_start(event: str, _value, **_kwargs) -> None:
+    if event == _BACKEND_COMPILE:
+        _XLA_CURRENT.loaded = False
+
+
+def _on_event(event: str, **_kwargs) -> None:
+    if event == _CACHE_HIT:
+        _XLA_CURRENT.loaded = True
+
+
+def _on_duration(event: str, secs: float, **_kwargs) -> None:
+    if not getattr(_XLA_CURRENT, "engine", False):
+        return
+    if event == _BACKEND_COMPILE:
+        if getattr(_XLA_CURRENT, "loaded", False):
+            _XLA_STATS["cache_loads"] += 1
+            _XLA_STATS["load_s"] += secs
+        else:
+            _XLA_STATS["xla_compiles"] += 1
+            _XLA_STATS["compile_s"] += secs
+    elif event in _TRACE_LOWER:
+        _XLA_STATS["trace_lower_s"] += secs
+
+
+def _listen_to_compiles() -> None:
+    """Register the `jax.monitoring` listeners behind `compile_stats`,
+    once per process, before the engine's first compile."""
+    if _XLA_LISTENING[0]:
+        return
+    jax.monitoring.register_scalar_listener(_on_compile_start)
+    jax.monitoring.register_event_listener(_on_event)
+    jax.monitoring.register_event_duration_secs_listener(_on_duration)
+    _XLA_LISTENING[0] = True
+
+
+def compile_stats() -> CompileStats:
+    """Compiles and persistent-cache loads of the engine's executables
+    since the last `reset_compile_count`, from JAX's compile events
+    (``jax.monitoring``) inside the engine's own calls; compiles of
+    anything else in the process are not counted."""
+    return CompileStats(lru_misses=_COMPILE_COUNT[0], **_XLA_STATS)
 
 
 def _with_wr(traces: dict) -> dict:
@@ -1106,6 +1228,7 @@ def _compiled(options: SimOptions, core: CoreParams, banks: int,
             f"on the scan backend; backend={options.backend!r} shards "
             f"through the global-cond NamedSharding path instead")
     _COMPILE_COUNT[0] += 1
+    _listen_to_compiles()
     if options.backend == "pallas":
         from repro.core.smla import pallas_engine   # lazy: imports us back
         raw = functools.partial(
@@ -1168,7 +1291,38 @@ def batched_simulate(params: dict, traces: dict,
     path: a fully-manual shard_map over the first `n` devices where each
     device's while_loop exits on its *local* shard (scan backend only;
     n_cells must be divisible by n)."""
-    options = _require_options(options, "batched_simulate").resolved()
+    fn, args = _batched_call(params, traces, options, core, banks,
+                             local_cond_devices, "batched_simulate")
+    with _engine_call():
+        return fn(*args)
+
+
+def batched_executable(params: dict, traces: dict,
+                       options: SimOptions, core: CoreParams,
+                       banks: int, *,
+                       local_cond_devices: int = 0) -> tuple:
+    """The program `batched_simulate` runs for these arguments, as one
+    object: ``(compiled, args)``, where ``compiled(*args)`` makes the
+    same call and ``compiled.as_text()`` is the optimised module whose
+    instruction names a profiler's device op events carry.  Where
+    `batched_simulate` ran these shapes before, it is the executable JAX
+    already holds, and nothing is built; else `compile_stats` counts its
+    compile or persistent-cache load.  Not under ``validate=True``,
+    which wraps the program."""
+    fn, args = _batched_call(params, traces, options, core, banks,
+                             local_cond_devices, "batched_executable")
+    if not hasattr(fn, "lower"):
+        raise ValueError("batched_executable: validate=True wraps the "
+                         "program in host-side checks")
+    with _engine_call():
+        return fn.lower(*args).compile(), args
+
+
+def _batched_call(params: dict, traces: dict, options: SimOptions,
+                  core: CoreParams, banks: int, local_cond_devices: int,
+                  fn_name: str) -> tuple:
+    """The executable of a batched call, and the arguments it takes."""
+    options = _require_options(options, fn_name).resolved()
     _check_backend(options)
     _apply_compile_cache(options)
     shard = int(local_cond_devices) if int(local_cond_devices) > 1 else 0
@@ -1179,7 +1333,7 @@ def batched_simulate(params: dict, traces: dict,
     r_max = params["dur"].shape[1]
     fn = _compiled(options, core, banks,
                    (n_cells, n_cores, n_req_max, r_max), True, shard)
-    return fn(_with_timing_defaults(params), _with_wr(traces))
+    return fn, (_with_timing_defaults(params), _with_wr(traces))
 
 
 def simulate(stack: StackConfig, traces: dict, options: SimOptions,
@@ -1196,5 +1350,7 @@ def simulate(stack: StackConfig, traces: dict, options: SimOptions,
     params["n_req"] = np.int32(n_req)
     fn = _compiled(options, core, stack.banks_per_rank,
                    (1, n_cores, n_req, stack.n_ranks), False)
-    return fn({k: jnp.asarray(v) for k, v in params.items()},
-              _with_wr({k: jnp.asarray(v) for k, v in traces.items()}))
+    params = {k: jnp.asarray(v) for k, v in params.items()}
+    traces = _with_wr({k: jnp.asarray(v) for k, v in traces.items()})
+    with _engine_call():
+        return fn(params, traces)

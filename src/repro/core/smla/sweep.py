@@ -49,6 +49,16 @@ wall-clock, never numerics.  The persistent JAX compilation cache
 executables survive the *process* and a journal resume skips both
 re-execution and recompilation.
 
+Under ``jax.profiler`` the pipeline marks its work with host spans on
+the clock of the device's execution events: ``smla.plan`` once, and per
+bucket (each span carrying the bucket's ``bucket`` ordinal)
+``smla.prepare`` (padding and stacking, on the producer thread),
+``smla.wait_prepare`` (the dispatching thread blocked on the producer),
+``smla.dispatch`` (``device_put`` and the call; trace, lower and compile
+on a cache miss), ``smla.harvest`` (the device-to-host copy, which waits
+for the execution) and ``smla.finalize`` (bookkeeping and ``on_bucket``).
+README's "Profiling a sweep" reads a stalled bucket off them.
+
 When more than one JAX device is visible, the stacked cell axis of each
 bucket is sharded across devices (bucket sizes are rounded up to a
 device multiple).  At ``LOCAL_COND_MIN_DEVICES`` or more devices the
@@ -733,16 +743,24 @@ def _build_arrays(bkt: _Bucket) -> tuple[dict, dict]:
     return params, traces
 
 
+def _span(name: str, **args):
+    """A host span ``smla.<name>`` in the profiler's trace, on the clock
+    of the device's execution events; a no-op when no profiler runs.
+    Spans of one bucket carry its ``bucket`` ordinal."""
+    return jax.profiler.TraceAnnotation("smla." + name, **args)
+
+
 def _prepare(bkt: _Bucket, journal: str | None):
     """One pipeline item: (bucket, journal-loaded metrics | None, params,
     traces) — either the bucket is already journaled (no arrays needed)
     or its padded arrays are built here."""
-    cached = (_journal_load(journal, bkt.jkey)
-              if bkt.jkey is not None else None)
-    if cached is not None:
-        return (bkt, cached, None, None)
-    params, traces = _build_arrays(bkt)
-    return (bkt, None, params, traces)
+    with _span("prepare", bucket=bkt.ordinal):
+        cached = (_journal_load(journal, bkt.jkey)
+                  if bkt.jkey is not None else None)
+        if cached is not None:
+            return (bkt, cached, None, None)
+        params, traces = _build_arrays(bkt)
+        return (bkt, None, params, traces)
 
 
 def _inline_items(plan: list[_Bucket], spec: SweepSpec):
@@ -759,6 +777,7 @@ class _Producer:
     unblocks and joins the thread (used on normal exit and on kill)."""
 
     def __init__(self, plan: list[_Bucket], spec: SweepSpec):
+        self._ordinals = [bkt.ordinal for bkt in plan]
         self._q: queue.Queue = queue.Queue(maxsize=max(1, spec.prefetch))
         self._stop = threading.Event()
         self._thread = threading.Thread(
@@ -786,15 +805,23 @@ class _Producer:
         except BaseException as exc:      # surface in the consumer thread
             self._put(("error", exc))
 
-    def __iter__(self):
+    def _get(self):
         while True:
             try:
-                tag, payload = self._q.get(timeout=0.5)
+                return self._q.get(timeout=0.5)
             except queue.Empty:
                 if not self._thread.is_alive():
                     raise RuntimeError(
                         "sweep producer thread died without reporting")
-                continue
+
+    def __iter__(self):
+        # items arrive in plan order: the k-th wait is for the k-th
+        # bucket, the last for the end of the plan
+        for k in range(len(self._ordinals) + 1):
+            args = ({"bucket": self._ordinals[k]}
+                    if k < len(self._ordinals) else {})
+            with _span("wait_prepare", **args):
+                tag, payload = self._get()
             if tag == "done":
                 return
             if tag == "error":
@@ -816,7 +843,8 @@ def _run_grid(spec: SweepSpec, opts: SimOptions,
     """Execute an (already policy-expanded) cell list as the streaming
     bucket pipeline.  See `run_sweep` for semantics."""
     n_dev = max(len(jax.devices()), 1)
-    plan = _plan(spec, opts, cells, n_dev)
+    with _span("plan"):
+        plan = _plan(spec, opts, cells, n_dev)
     n = len(cells)
     refs: list = [None] * n
     chunks: list[int] = [0] * n
@@ -840,14 +868,15 @@ def _run_grid(spec: SweepSpec, opts: SimOptions,
             spec.on_bucket(progress[0], len(plan), wall, progress[1] / wall)
 
     def _dispatch(bkt: _Bucket, params: dict, traces: dict) -> dict:
-        if bkt.sharding is not None:
-            params = jax.device_put(params, bkt.sharding)
-            traces = jax.device_put(traces, bkt.sharding)
-        # resolved at call time through the module so tests can inject
-        # failures by monkeypatching engine.batched_simulate
-        return engine.batched_simulate(
-            params, traces, opts.with_chunk(bkt.chunk_b), spec.core,
-            bkt.banks, local_cond_devices=bkt.local_cond)
+        with _span("dispatch", bucket=bkt.ordinal):
+            if bkt.sharding is not None:
+                params = jax.device_put(params, bkt.sharding)
+                traces = jax.device_put(traces, bkt.sharding)
+            # resolved at call time through the module so tests can inject
+            # failures by monkeypatching engine.batched_simulate
+            return engine.batched_simulate(
+                params, traces, opts.with_chunk(bkt.chunk_b), spec.core,
+                bkt.banks, local_cond_devices=bkt.local_cond)
 
     def _record_failure(bkt: _Bucket, exc: Exception) -> None:
         tags = list(dict.fromkeys(bkt.group[j].name for j in bkt.positions))
@@ -860,35 +889,37 @@ def _run_grid(spec: SweepSpec, opts: SimOptions,
         _mark_done(0)
 
     def _finalize(bkt: _Bucket, out_np: dict, save: bool) -> None:
-        if save and bkt.jkey is not None:
-            _journal_save(spec.journal, bkt.jkey, out_np)
-        if bkt.jkey is not None:
-            data = _BucketData(path=os.path.join(spec.journal,
-                                                 bkt.jkey + ".npz"))
-        else:
-            data = _BucketData(arrays=out_np)
-        eff = engine.effective_chunk(opts.horizon, bkt.chunk_b)
-        # duplicate pad entries land on the same original index with
-        # bit-identical values — assigning them again is harmless.
-        meta = {"cells": [], "chunk": eff, "est_cycles": [],
-                "measured_cycles": [], "n_rows": len(bkt.positions),
-                "chunks_run": int(np.max(np.asarray(out_np["chunks_run"])))}
-        mk = np.asarray(out_np["makespan_ns"])
-        seen: set[int] = set()
-        for j_pos, j in enumerate(bkt.positions):
-            refs[bkt.idxs[j]] = (data, j_pos)
-            chunks[bkt.idxs[j]] = eff
-            if j in seen:
-                continue                     # pad duplicate
-            seen.add(j)
-            meta["cells"].append(bkt.group[j].name)
-            meta["est_cycles"].append(float(bkt.est[j]))
-            meta["measured_cycles"].append(
-                float(mk[j_pos]) / float(bkt.group[j].stack.unit_ns))
-        meta["est_max"] = max(meta["est_cycles"])
-        meta["measured_max"] = max(meta["measured_cycles"])
-        bucket_meta.append(meta)
-        _mark_done(len(seen))
+        with _span("finalize", bucket=bkt.ordinal):
+            if save and bkt.jkey is not None:
+                _journal_save(spec.journal, bkt.jkey, out_np)
+            if bkt.jkey is not None:
+                data = _BucketData(path=os.path.join(spec.journal,
+                                                     bkt.jkey + ".npz"))
+            else:
+                data = _BucketData(arrays=out_np)
+            eff = engine.effective_chunk(opts.horizon, bkt.chunk_b)
+            # duplicate pad entries land on the same original index with
+            # bit-identical values — assigning them again is harmless.
+            meta = {"cells": [], "chunk": eff, "est_cycles": [],
+                    "measured_cycles": [], "n_rows": len(bkt.positions),
+                    "chunks_run": int(np.max(
+                        np.asarray(out_np["chunks_run"])))}
+            mk = np.asarray(out_np["makespan_ns"])
+            seen: set[int] = set()
+            for j_pos, j in enumerate(bkt.positions):
+                refs[bkt.idxs[j]] = (data, j_pos)
+                chunks[bkt.idxs[j]] = eff
+                if j in seen:
+                    continue                     # pad duplicate
+                seen.add(j)
+                meta["cells"].append(bkt.group[j].name)
+                meta["est_cycles"].append(float(bkt.est[j]))
+                meta["measured_cycles"].append(
+                    float(mk[j_pos]) / float(bkt.group[j].stack.unit_ns))
+            meta["est_max"] = max(meta["est_cycles"])
+            meta["measured_max"] = max(meta["measured_cycles"])
+            bucket_meta.append(meta)
+            _mark_done(len(seen))
 
     def _harvest_head() -> None:
         entry = pending.popleft()
@@ -897,7 +928,8 @@ def _run_grid(spec: SweepSpec, opts: SimOptions,
             return
         _, bkt, out, attempts, params, traces = entry
         try:
-            out_np = {k: np.asarray(v) for k, v in out.items()}
+            with _span("harvest", bucket=bkt.ordinal):
+                out_np = {k: np.asarray(v) for k, v in out.items()}
         except Exception as exc:
             # an asynchronously-dispatched device error surfaces at copy
             # time: re-run the bucket synchronously under whatever retry

@@ -16,6 +16,9 @@ library while a module is imported would make every test worker try to
 take it.  The persistent compile cache is off around these compiles, as
 an executable built for a described chip cannot be read back without one.
 """
+import collections
+import re
+
 import jax
 import numpy as np
 import pytest
@@ -64,10 +67,7 @@ def _fig12_c16_cells():
     return [c for c in cells if c.name.startswith("c16/")]
 
 
-@pytest.mark.parametrize("cells_of", [_fig11_cells, _fig12_c16_cells],
-                         ids=["fig11", "fig12_c16"])
-def test_scan_bucket_compiles_for_v5e(topo, no_persistent_cache, cells_of):
-    cells = cells_of()
+def _compile_first_bucket(topo, cells):
     opts = SimOptions(horizon=default_horizon(cells))
     spec = sweep.SweepSpec(tuple(cells), options=opts)
     bkt = sweep._plan(spec, opts, cells, 1)[0]
@@ -80,10 +80,57 @@ def test_scan_bucket_compiles_for_v5e(topo, no_persistent_cache, cells_of):
     one_chip = SingleDeviceSharding(topo.devices[0])
     shape_of = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
         np.shape(a), np.asarray(a).dtype, sharding=one_chip)
-    compiled = fn.lower(jax.tree_util.tree_map(shape_of, params),
-                        jax.tree_util.tree_map(shape_of, traces)).compile()
+    return fn.lower(jax.tree_util.tree_map(shape_of, params),
+                    jax.tree_util.tree_map(shape_of, traces)).compile()
 
+
+@pytest.mark.parametrize("cells_of", [_fig11_cells, _fig12_c16_cells],
+                         ids=["fig11", "fig12_c16"])
+def test_scan_bucket_compiles_for_v5e(topo, no_persistent_cache, cells_of):
+    compiled = _compile_first_bucket(topo, cells_of())
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes + mem.generated_code_size_in_bytes)
     assert 0 < used < V5E_HBM_BYTES, mem
+
+
+#: instructions that are no kernel of their own on the chip
+_NOT_OPS = {"parameter", "get-tuple-element", "tuple", "constant",
+            "bitcast"}
+
+
+def _scan_body_scopes(hlo: str) -> collections.Counter:
+    """The innermost stage scope of each op of the compiled scan body (the
+    while-loop body whose ops carry the most stage scopes), ``None`` for
+    an op in no stage scope."""
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%(\S+) .*\{$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif line == "}":
+            cur = None
+        elif cur is not None and " = " in line:
+            op = re.search(r" = .*? ([a-z][a-z0-9\-]*)\(", line).group(1)
+            name = re.search(r'op_name="([^"]*)"', line)
+            found = [c.removeprefix(engine.SCOPE_PREFIX)
+                     for c in (name.group(1) if name else "").split("/")
+                     if c.startswith(engine.SCOPE_PREFIX)]
+            scope = found[-1] if found else None
+            if op not in _NOT_OPS:
+                cur.append(scope if scope in engine.STAGE_SCOPES else None)
+    bodies = re.findall(r" while\(.*?body=%([^,\s]+)", hlo)
+    body = max(bodies, key=lambda b: sum(s is not None for s in comps[b]))
+    return collections.Counter(comps[body])
+
+
+def test_stage_scopes_label_the_compiled_scan_body(topo,
+                                                   no_persistent_cache):
+    """Every stage scope survives XLA's fusion onto ops of the full-size
+    Fig. 12 bucket's scan body, and few ops (copies XLA inserts, the
+    loop's control) carry none: the stage probe's attribution rests on
+    both."""
+    scopes = _scan_body_scopes(
+        _compile_first_bucket(topo, _fig12_c16_cells()).as_text())
+    assert set(engine.STAGE_SCOPES) <= set(scopes), scopes
+    assert scopes[None] < 0.3 * sum(scopes.values()), scopes

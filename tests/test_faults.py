@@ -424,7 +424,11 @@ if HAVE_HYPOTHESIS:
             assert len(lay["ref_derate"]) == lay["n_ranks"]
             assert set(np.asarray(lay["ref_derate"]).tolist()) <= \
                 {1, fc.retention_derate}
-            if fc.degrade == DegradeMode.COLLAPSE and not fc.is_clean:
+            if not fc.effective_dead(_LAYERS):
+                # no layer lost (weak ranks and ECC keep every rank):
+                # the clean rank count under every mode
+                assert lay["n_ranks"] == sc.n_ranks
+            elif fc.degrade == DegradeMode.COLLAPSE:
                 assert lay["n_ranks"] == 1
             # params always pad to the PHYSICAL rank count: the fault
             # axis can never change static shapes
