@@ -359,14 +359,16 @@ def _stage_refresh(st, aux, t, ctx):
     ref_next = jnp.where(postpone, ref_next + t_refi_eff, ref_next)
     ref_due = ref_due & ~postpone
 
-    in_flight_q = jnp.where(qv & (qphase >= 2), 1, 0)
+    # issued/granted entries per (rank, bank); qr < R and qb < B (the
+    # traces are reduced modulo n_ranks and B), so a rank's count is the
+    # sum of its banks'
+    in_flight_rb = policies.counts_by(qv & (qphase >= 2), qr * B + qb,
+                                      R * B).reshape(R, B)
     # all-bank drain condition: the whole rank idle, nothing in flight
     bank_idle = (bank_busy <= t).all(axis=1)
-    in_flight = jax.ops.segment_sum(in_flight_q, qr, num_segments=R) > 0
+    in_flight = in_flight_rb.sum(axis=1) > 0
     can_ab = bank_idle & ~in_flight
     # per-bank drain condition: only the target bank idle / drained
-    in_flight_rb = jax.ops.segment_sum(in_flight_q, qr * B + qb,
-                                       num_segments=R * B).reshape(R, B)
     ranks = jnp.arange(R, dtype=jnp.int32)
     can_pb = (bank_busy[ranks, ref_bank] <= t) \
         & ~(in_flight_rb[ranks, ref_bank] > 0)
@@ -649,23 +651,24 @@ def _stage_retire(st, aux, t, ctx):
     observable (nonzero even at window=1 under FR-FCFS, which already
     completes across banks out of order; the tagged window makes it
     measurable and lets `OooSelect` widen it deliberately)."""
-    n_cores, qc = ctx["n_cores"], ctx["qc"]
+    n_cores, Wd = ctx["n_cores"], ctx["Wd"]
     qv, qphase, qdone, qwr = (st["qv"], st["qphase"], st["qdone"],
                               st["qwr"])
     fin = qv & (qphase == 4) & (qdone <= t)
-    fin_per_core = jax.ops.segment_sum(jnp.where(fin, 1, 0), qc,
-                                       num_segments=n_cores)
+    # per-core reductions over each core's own Wd-slot window segment
+    by_core = lambda x: x.reshape(n_cores, Wd)  # noqa: E731
+    fin_per_core = by_core(jnp.where(fin, 1, 0)).sum(axis=1)
     st["served"] = st["served"] + fin_per_core
-    st["c_finish"] = jnp.maximum(st["c_finish"], jax.ops.segment_max(
-        jnp.where(fin, t, -1), qc, num_segments=n_cores))
+    st["c_finish"] = jnp.maximum(st["c_finish"], by_core(
+        jnp.where(fin, t, -1)).max(axis=1))
     st["c_out"] = st["c_out"] - fin_per_core
     st["n_wr"] = st["n_wr"] + jnp.where(fin & qwr, 1, 0).sum()
     # a retire is out-of-order when the same core still has an older tag
     # in flight (valid, not retiring this cycle)
     rem_tag = jnp.where(qv & ~fin, st["qtag"], BIG)
-    min_rem = jax.ops.segment_min(rem_tag, qc, num_segments=n_cores)
+    min_rem = by_core(rem_tag).min(axis=1, keepdims=True)
     st["n_ooo_retire"] = st["n_ooo_retire"] + jnp.where(
-        fin & (min_rem[qc] < st["qtag"]), 1, 0).sum()
+        by_core(fin) & (min_rem < by_core(st["qtag"])), 1, 0).sum()
     st["qv"] = qv & ~fin
     st["qphase"] = jnp.where(fin, 0, qphase)
     return st, aux
@@ -681,9 +684,7 @@ def _stage_progress(st, aux, t, ctx):
     n_cores, n_req, core = ctx["n_cores"], ctx["n_req"], ctx["core"]
     tr_inst = ctx["traces"]["inst"]
     inst_or_big = jnp.where(st["qv"], st["qinst"], jnp.float32(1e30))
-    oldest = jax.ops.segment_min(inst_or_big, ctx["qc"],
-                                 num_segments=n_cores)
-    oldest = jnp.minimum(oldest, jnp.float32(1e30))
+    oldest = inst_or_big.reshape(n_cores, ctx["Wd"]).min(axis=1)
     window_ok = (st["c_inst"] - oldest) < core.inst_window
     nxt_inst = jnp.where(st["c_next"] < n_req,
                          tr_inst[jnp.arange(n_cores),
@@ -712,8 +713,7 @@ def _stage_power(st, aux, t, ctx):
     refresh blackout, which keeps banks busy) are disjoint by
     construction."""
     R, pol = ctx["R"], ctx["pol"]
-    pending = jax.ops.segment_sum(jnp.where(st["qv"], 1, 0), st["qr"],
-                                  num_segments=R) > 0
+    pending = policies.counts_by(st["qv"], st["qr"], R) > 0
     rank_idle = (st["bank_busy"] <= t).all(axis=1) & ~pending \
         & ctx["real_rank"]
     st["idle_since"] = jnp.where(rank_idle, st["idle_since"], t + 1)
@@ -829,9 +829,9 @@ def _sim_core(params: dict, traces: dict, horizon: int, core: CoreParams,
         "pol": pol,
         "wq_hi": wq_hi, "wq_lo": wq_lo,
         # window layout: the owning core of each flat slot is a static
-        # function of position (slot // Wd) — no per-entry core field
+        # function of position (slot // Wd) — no per-entry core field, and
+        # a per-core reduction is a (n_cores, Wd) reshape
         "Wd": Wd,
-        "qc": jnp.arange(QT, dtype=jnp.int32) // Wd,
         "traces": {
             "inst": traces["inst"].astype(jnp.float32),
             "rank": traces["rank"].astype(jnp.int32) % params["n_ranks"],

@@ -50,10 +50,13 @@ places in turn:
    whole array or a multiple of 128.
 2. The chunk's ``lax.scan`` over fast cycles (`engine._sim_core`) has an
    extensive input, and Mosaic's scan lowering does not implement it.
-3. ``jax.ops.segment_sum`` (first reached in `policies.refresh_demand`)
-   lowers to a scatter-add, which the Pallas TPU lowering does not
-   implement; `engine.py` has about two dozen more ``segment_*`` and
-   ``.at[]`` sites.
+3. Scatters, which the Pallas TPU lowering does not implement.  The
+   stages call no ``jax.ops.segment_*`` any more:
+   `policies.refresh_demand`, where the first ``segment_sum`` sat, and
+   the other per-rank and per-core queue counts are a compare and a sum
+   (`policies.counts_by`) or a reshape and a reduction.  The 14
+   ``.at[]`` updates of the enqueue, schedule and transfer stages
+   still lower to scatters.
 
 Porting the kernel is worth it only if a chip measurement shows the
 fused layout can beat the scan backend.

@@ -48,7 +48,6 @@ so draining writes outrank even row-hit reads, as real write bursts do.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -227,6 +226,18 @@ def refresh_bank_mask(pol: dict, ref_bank, banks: int):
     return jnp.where(pol["per_bank"], one_hot, True)
 
 
+def counts_by(mask, idx, n: int):
+    """(n,) int32: how many queue entries with `mask` set have `idx` equal
+    to each bin; an index outside [0, n) counts nowhere.
+
+    The same counts as ``jax.ops.segment_sum(mask, idx, n)``, computed as
+    one compare against the bins and a sum over the queue axis: the
+    scatter-add that ``segment_sum`` lowers to applies its updates one by
+    one on the TPU, several times the cost of this one fusion."""
+    hit = (idx[:, None] == jnp.arange(n, dtype=idx.dtype)) & mask[:, None]
+    return hit.sum(axis=0, dtype=jnp.int32)
+
+
 def refresh_demand(pol: dict, draining, qv, qphase, qwr, qr, n_ranks: int):
     """(R,) mask: does rank r have *demand* a postponed refresh would
     serve sooner?  Demand is any valid queue entry for the rank — except
@@ -235,8 +246,8 @@ def refresh_demand(pol: dict, draining, qv, qphase, qwr, qr, n_ranks: int):
     write-shadow window is exactly where owed refreshes pull in (the
     ROADMAP's drain-aware refresh scheduling)."""
     held_wr = pol["drain_full"] & ~draining
-    counted = jnp.where(qv & (qphase >= 1) & ~(qwr & held_wr), 1, 0)
-    return jax.ops.segment_sum(counted, qr, num_segments=n_ranks) > 0
+    counted = qv & (qphase >= 1) & ~(qwr & held_wr)
+    return counts_by(counted, qr, n_ranks) > 0
 
 
 def cas_refresh_block(pol: dict, ref_due, ref_bank, qr, qb):

@@ -99,10 +99,12 @@ _NOT_OPS = {"parameter", "get-tuple-element", "tuple", "constant",
             "bitcast"}
 
 
-def _scan_body_scopes(hlo: str) -> collections.Counter:
-    """The innermost stage scope of each op of the compiled scan body (the
-    while-loop body whose ops carry the most stage scopes), ``None`` for
-    an op in no stage scope."""
+def _scan_body_ops(hlo: str) -> list:
+    """``(scope, kinds)`` for each op of the compiled scan body (the
+    while-loop body whose ops carry the most stage scopes): the innermost
+    stage scope, ``None`` for an op in no stage scope, and the set of the
+    op's instruction kinds, those of every computation it calls (a
+    fusion's body) included."""
     comps, cur = {}, None
     for line in hlo.splitlines():
         head = re.match(r"^(?:ENTRY )?%(\S+) .*\{$", line)
@@ -112,16 +114,32 @@ def _scan_body_scopes(hlo: str) -> collections.Counter:
             cur = None
         elif cur is not None and " = " in line:
             op = re.search(r" = .*? ([a-z][a-z0-9\-]*)\(", line).group(1)
+            called = tuple(re.findall(r"(?:calls|to_apply)=%([^,\s]+)",
+                                      line))
             name = re.search(r'op_name="([^"]*)"', line)
             found = [c.removeprefix(engine.SCOPE_PREFIX)
                      for c in (name.group(1) if name else "").split("/")
                      if c.startswith(engine.SCOPE_PREFIX)]
             scope = found[-1] if found else None
-            if op not in _NOT_OPS:
-                cur.append(scope if scope in engine.STAGE_SCOPES else None)
+            cur.append((op, called,
+                        scope if scope in engine.STAGE_SCOPES else None))
+
+    def kinds(op, called):
+        out = {op}
+        for c in called:
+            for sub in comps[c]:
+                out |= kinds(*sub[:2])
+        return out
+
     bodies = re.findall(r" while\(.*?body=%([^,\s]+)", hlo)
-    body = max(bodies, key=lambda b: sum(s is not None for s in comps[b]))
-    return collections.Counter(comps[body])
+    body = max(bodies, key=lambda b: sum(o[2] is not None for o in comps[b]))
+    return [(scope, kinds(op, called)) for op, called, scope in comps[body]
+            if op not in _NOT_OPS]
+
+
+def _scan_body_scopes(hlo: str) -> collections.Counter:
+    """How many ops of the compiled scan body each stage scope holds."""
+    return collections.Counter(scope for scope, _ in _scan_body_ops(hlo))
 
 
 def test_stage_scopes_label_the_compiled_scan_body(topo,
@@ -134,3 +152,19 @@ def test_stage_scopes_label_the_compiled_scan_body(topo,
         _compile_first_bucket(topo, _fig12_c16_cells()).as_text())
     assert set(engine.STAGE_SCOPES) <= set(scopes), scopes
     assert scopes[None] < 0.3 * sum(scopes.values()), scopes
+
+
+def test_queue_counts_compile_to_no_scatter(topo, no_persistent_cache):
+    """The per-rank and per-core queue reductions of the refresh, retire,
+    progress and power stages compile to dense reductions: no op of those
+    scopes in the full-size Fig. 12 bucket's scan body is a scatter or a
+    fusion holding one.  The chip applies a scatter's updates one by one,
+    so each such op cost several times a dense fusion."""
+    ops = _scan_body_ops(
+        _compile_first_bucket(topo, _fig12_c16_cells()).as_text())
+    scattering = [(scope, sorted(kinds)) for scope, kinds in ops
+                  if scope in ("refresh", "retire", "progress", "power")
+                  and any(k.startswith("scatter") for k in kinds)]
+    assert not scattering, scattering
+    # the parse does see scatters: the `.at[]` updates of other stages
+    assert any(k.startswith("scatter") for _, kinds in ops for k in kinds)

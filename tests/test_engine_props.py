@@ -14,6 +14,8 @@ of XLA compiles, not one per example.
 """
 import dataclasses
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -302,6 +304,82 @@ def test_table1_write_and_powerdown_priced_from_metrics():
                              pd_frac=1.0)
     assert full_pd.standby_nj == pytest.approx(
         stack.layers * E.PD_MA * stack.vdd * t_ns * 1e-3)
+
+
+# ----------------------------------------------------------------------------
+# queue reductions: dense forms against the segment ops they replaced
+# ----------------------------------------------------------------------------
+
+def _segment_counts(mask, idx, n):
+    """The reference: the scatter-add the engine used to count with."""
+    return jax.ops.segment_sum(jnp.where(mask, 1, 0), idx, num_segments=n)
+
+
+@pytest.mark.parametrize("R,B,QT,rows", [(4, 8, 32, 1), (8, 2, 16, 14),
+                                         (8, 8, 8, 4), (2, 4, 64, 3),
+                                         (1, 1, 4, 1)])
+def test_counts_by_matches_segment_sum(R, B, QT, rows):
+    """`policies.counts_by` counts what `segment_sum` counted, per rank and
+    per (rank, bank), row by row of a vmapped batch; entries with no
+    weight may point anywhere, a padded rank above `n_ranks` included,
+    and count nowhere."""
+    rng = np.random.default_rng([R, B, QT, rows])
+    n_ranks = max(R - 1, 1)
+    mask = rng.random((rows, QT)) < 0.6
+    qr = np.where(mask, rng.integers(0, n_ranks, (rows, QT)),
+                  rng.integers(0, R, (rows, QT)))
+    qr[~mask & (rng.random((rows, QT)) < 0.5)] = R - 1   # a padded rank
+    qb = rng.integers(0, B, (rows, QT))
+    mask, qr, qb = jnp.asarray(mask), jnp.asarray(qr), jnp.asarray(qb)
+
+    counts = jax.vmap(policies.counts_by, (0, 0, None))
+    reference = jax.vmap(_segment_counts, (0, 0, None))
+    per_rank = counts(mask, qr, R)
+    np.testing.assert_array_equal(per_rank, reference(mask, qr, R))
+    per_bank = counts(mask, qr * B + qb, R * B)
+    np.testing.assert_array_equal(per_bank,
+                                  reference(mask, qr * B + qb, R * B))
+    # the refresh stage's per-rank in-flight test, from the per-bank counts
+    np.testing.assert_array_equal(
+        per_bank.reshape(rows, R, B).sum(axis=2) > 0, per_rank > 0)
+    assert per_rank.dtype == jnp.int32
+    # a padded rank never counts: every weighted entry names a real one
+    np.testing.assert_array_equal(np.asarray(per_rank)[:, n_ranks:], 0)
+
+
+@pytest.mark.parametrize("n_cores,Wd,rows", [(4, 8, 4), (2, 8, 14),
+                                             (16, 1, 1)])
+def test_window_reshape_reductions_match_segment_ops(n_cores, Wd, rows):
+    """Per-core reductions over the static window segments
+    (slot // Wd, every segment non-empty) as a (n_cores, Wd) reshape equal
+    `segment_sum` / `segment_max` / `segment_min` over the core index."""
+    rng = np.random.default_rng([n_cores, Wd, rows])
+    QT = n_cores * Wd
+    qc = jnp.arange(QT, dtype=jnp.int32) // Wd
+    fin = jnp.asarray(rng.random((rows, QT)) < 0.3)
+    tag = jnp.asarray(rng.integers(0, 1000, (rows, QT)), jnp.int32)
+    inst = jnp.where(jnp.asarray(rng.random((rows, QT)) < 0.5),
+                     jnp.asarray(rng.random((rows, QT)) * 1e4, jnp.float32),
+                     jnp.float32(1e30))
+
+    def dense(fin, tag, inst):
+        by_core = lambda x: x.reshape(n_cores, Wd)  # noqa: E731
+        return (by_core(jnp.where(fin, 1, 0)).sum(axis=1),
+                by_core(jnp.where(fin, tag, -1)).max(axis=1),
+                by_core(tag).min(axis=1), by_core(inst).min(axis=1))
+
+    def segments(fin, tag, inst):
+        return (jax.ops.segment_sum(jnp.where(fin, 1, 0), qc,
+                                    num_segments=n_cores),
+                jax.ops.segment_max(jnp.where(fin, tag, -1), qc,
+                                    num_segments=n_cores),
+                jax.ops.segment_min(tag, qc, num_segments=n_cores),
+                jax.ops.segment_min(inst, qc, num_segments=n_cores))
+
+    for got, want in zip(jax.vmap(dense)(fin, tag, inst),
+                         jax.vmap(segments)(fin, tag, inst)):
+        assert got.dtype == want.dtype
+        np.testing.assert_array_equal(got, want)
 
 
 # ----------------------------------------------------------------------------
