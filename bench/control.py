@@ -2,14 +2,15 @@
 
     python3 bench/control.py --workload <name> --seeds 11 12 13
 
-The control is the reference put in the program's place with its
-instruction counts in bfloat16, the precision below the float32 the
-configuration states.  For each seed: as many cells of the seed's job 0
-as a run compares, the longest among them, each simulated by the
-reference in float32 and by the control, and compared as a run compares
-the program.  Prints one JSON line per seed with the numbers a run
-compares, beside the limits ``check.py`` holds them to.  Neither needs
-a chip; the benchmark's runs never run this.
+The control is the configuration's reference (``registry.reference``)
+put in the program's place with its instruction counts in bfloat16, the
+precision below the float32 the configuration states.  For each seed:
+as many cells of the seed's job 0 as a run compares, the longest among
+them, each simulated by the reference in float32 and by the control,
+and compared as a run compares the program.  Prints one JSON line per
+seed with the numbers a run compares, beside the limits ``check.py``
+holds them to.  Neither needs a chip; the benchmark's runs never run
+this.
 """
 import argparse
 import json
@@ -26,7 +27,6 @@ def main(argv=None) -> int:
     import numpy as np
 
     from bench.lib import check, gen, registry
-    from bench.reference import controller
 
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--workload", required=True)
@@ -35,20 +35,21 @@ def main(argv=None) -> int:
     bm = registry.benchmark()
     wl = registry.workload(bm, args.workload)
     cfg = registry.config(bm, wl["config"])
+    simulate_many = registry.reference(cfg).simulate_many
     traffic = registry.traffic(wl["traffic"])
     for seed in args.seeds:
         t0 = time.perf_counter()
         pairs = gen.make_job(cfg, traffic, seed, 0).expanded()
         cells = [check.reference_cell(cfg, traffic, c, p) for c, p in pairs]
-        want = controller.simulate_many(cells)
+        want = simulate_many(cells)
         longest = max(range(len(pairs)),
                       key=lambda i: float(want[i]["makespan_ns"]))
         rest = [i for i in range(len(pairs)) if i != longest]
         rng = gen.job_rng(seed, 2**32)
         picked = [longest] + sorted(int(i) for i in rng.choice(
             rest, min(check.SAMPLE - 1, len(rest)), replace=False))
-        control = controller.simulate_many([cells[i] for i in picked],
-                                           ml_dtypes.bfloat16)
+        control = simulate_many([cells[i] for i in picked],
+                                ml_dtypes.bfloat16)
         gaps = [check.compare(got, want[i])
                 for got, i in zip(control, picked)]
         failed = sum(not (np.all(o["served"] == traffic["n_req"])

@@ -12,7 +12,8 @@ window finished, against the benchmark's own reference.
 * ``cells_checked``: the sample's size; at least 1.
 
 The sample is drawn from the run's seed and always holds the cell with
-the longest makespan.  The reference (``bench/reference/controller.py``)
+the longest makespan.  The configuration's reference
+(``registry.reference``; by default ``bench/reference/controller.py``)
 simulates each sampled cell alone, request by request, in plain Python
 on the host, in worker processes of its own that never touch JAX.
 ``chunks_run`` (an execution detail that depends on the bucket's chunk
@@ -24,9 +25,7 @@ import dataclasses
 
 import numpy as np
 
-from bench.lib import gen
-from bench.reference import controller
-from bench.reference import params as ref_params
+from bench.lib import gen, registry
 
 #: cells compared with the reference in every run
 SAMPLE = 8
@@ -84,9 +83,9 @@ def reference_cell(config: dict, traffic: dict, cell: gen.Cell,
                    policy: dict) -> tuple:
     """The reference's arguments for one cell: ``(channel, traces, core,
     horizon)``."""
-    ch = ref_params.channel(config["stack"],
-                            config["organisations"][cell.org], policy,
-                            traffic["n_req"])
+    ch = registry.reference(config).channel(
+        config["stack"], config["organisations"][cell.org], policy,
+        traffic["n_req"])
     return ch, cell.traces, config["core"], traffic["horizon"]
 
 
@@ -115,7 +114,7 @@ def run_checks(window, config: dict, traffic: dict, seed: int
     picked = sample(window, seed)
     by_job = {jr.job.index: jr for jr in window.jobs}
     pairs = [by_job[j].grid.cells[name] for j, name in picked]
-    wants = controller.simulate_many(
+    wants = registry.reference(config).simulate_many(
         [reference_cell(config, traffic, c, p) for c, p in pairs])
     gaps = [compare(by_job[j].result[name], want)
             for (j, name), want in zip(picked, wants)]

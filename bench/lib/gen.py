@@ -32,7 +32,7 @@ import dataclasses
 
 import numpy as np
 
-from bench.reference.params import n_ranks
+from bench.lib import registry
 
 
 @dataclasses.dataclass(frozen=True)
@@ -120,6 +120,7 @@ def make_job(config: dict, traffic: dict, seed: int, k: int) -> Job:
     rng = job_rng(seed, k)              # the seed's relabelling of them
     table = {w["name"]: w for w in config["assumed"]["workload_table"]}
     stack = config["stack"]
+    n_ranks = registry.reference(config).n_ranks
     cells = []
     for m, mix in enumerate(traffic["mixes"]):
         trace_seed = int(work.integers(2**31))

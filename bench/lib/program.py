@@ -2,10 +2,13 @@
 
 Everything the harness takes from the program passes through here:
 ``sweep.run_sweep`` and the public types it is called with, the result's
-per-cell metrics and bucket records, and ``engine.compile_count``.  The warm-up also reaches the sweep planner
-(``sweep._plan``, ``sweep._build_arrays``) and ``engine.batched_simulate``,
-as ``chip_smoke.py`` does, so that it loads exactly the executables the
-window dispatches without running a bucket's real work.
+per-cell metrics and bucket records, and ``engine.compile_count``.  The
+warm-up also reaches the sweep planner (``sweep._plan``,
+``sweep._build_arrays``) and ``engine.batched_simulate``, as
+``chip_smoke.py`` does, so that it loads exactly the executables the
+window dispatches without running a bucket's real work; and the layout
+of a bucket's rows over the devices comes from the planner's
+``sweep._resolve_cond_sharding``.
 """
 from __future__ import annotations
 
@@ -79,6 +82,17 @@ def grid(job: Job, config: dict, traffic: dict, on_bucket=None) -> Grid:
 
 def run(g: Grid) -> sweep.SweepResult:
     return sweep.run_sweep(g.spec)
+
+
+def loop_layout(g: Grid) -> tuple[int, int]:
+    """``(devices, loops)`` of every bucket the job runs: its rows split
+    in contiguous blocks over `devices` chips, and stepped by `loops`
+    while-loops that each exit when their own rows are done, one over
+    every chip (global exit) or one a chip (local exit)."""
+    n_dev = len(jax.devices())
+    _, local = sweep._resolve_cond_sharding(
+        g.spec, g.spec.resolved_options(), n_dev)
+    return n_dev, (local if local > 1 else 1)
 
 
 def _plan(spec: sweep.SweepSpec) -> list:

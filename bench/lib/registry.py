@@ -6,13 +6,20 @@ a new entry, never an edit:
 * a traffic mix: ``bench/traffic/<traffic>.json``;
 * a metric (end-to-end or per-layer): ``bench/metrics/<name>.py``, whose
   ``read(run)`` returns the number, or None where the run holds nothing
-  to read it from.
+  to read it from;
+* a configuration's reference: the module ``bench/reference/<module>.py``
+  that its file names under ``"reference"``; a file without the key is
+  checked by ``bench/reference/params.py`` and ``controller.py``.
 """
 from __future__ import annotations
 
+import dataclasses
+import importlib
 import importlib.util
 import json
 import os
+import re
+from typing import Callable
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -62,3 +69,44 @@ def reader(name: str, bench: str = BENCH):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
+
+
+@dataclasses.dataclass(frozen=True)
+class Reference:
+    """What the harness takes from a configuration's plain reference:
+
+    * ``channel(stack, org, policy, n_req)``: one cell's channel, from the
+      configuration's ``stack`` block, one of its ``organisations`` and a
+      map from policy axis to value name;
+    * ``simulate_many(cells, fdtype=numpy.float32)``: each ``(channel,
+      traces, core, horizon)`` cell's statistics under the program's
+      names, with its instruction counts in `fdtype` (the control runs
+      the precision below the configuration's);
+    * ``n_ranks(stack, org)``: the ranks an organisation has, which the
+      traffic generator draws addresses over;
+    * ``unit_ns(stack)``: one fast cycle in ns, the unit of the work
+      accounting.
+    """
+    channel: Callable
+    simulate_many: Callable
+    n_ranks: Callable
+    unit_ns: Callable
+
+
+def reference(config: dict) -> Reference:
+    """The reference of a configuration: the module under
+    ``bench/reference/`` its ``"reference"`` key names, which provides
+    the four functions of `Reference`; without the key, ``params.py``'s
+    ``channel``, ``n_ranks`` and ``unit_ns`` and ``controller.py``'s
+    ``simulate_many``.  A reference imports nothing of the program."""
+    name = config.get("reference")
+    if name is None:
+        from bench.reference import controller, params
+        return Reference(params.channel, controller.simulate_many,
+                         params.n_ranks, params.unit_ns)
+    if not re.fullmatch(r"[A-Za-z_][A-Za-z0-9_]*", name):
+        raise ValueError(f"reference {name!r} is not a module name under "
+                         f"bench/reference/")
+    mod = importlib.import_module(f"bench.reference.{name}")
+    return Reference(mod.channel, mod.simulate_many, mod.n_ranks,
+                     mod.unit_ns)

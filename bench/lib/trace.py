@@ -17,17 +17,35 @@ its completion flag (``EXEC_END``), and no earlier than the previous
 execution's end, since the chip runs one program at a time.  These are
 host timestamps: an execution's interval holds the device's run and the
 runtime's latency to notice its end, so the busy time reads high, never
-low.  The runtime's events name no device, so a run on more than one
-chip cannot be reduced.  ``reduce`` then gives:
+low.
+
+**Which device.**  The issue and the completion-flag read carry no
+device themselves; the events beside them do, by their
+``device_ordinal`` stat.  An issue holds the runtime's
+``DoEnqueueProgram`` on its host line, and a read is followed on its
+line by the ``CompleteCallbacks`` of the execution it saw end.  Both
+name the same ordinal as the ``core_id`` of ``tpu::System::Execute``:
+in a host-only trace of a tiny ``smla4-mp16-x4`` run on a TPU v5e-4,
+each device's completions sit on a thread of their own while one
+issuing thread serves several devices, every ``DoEnqueueProgram`` and
+``CompleteCallbacks`` event carries its device's ordinal, and each
+execution's enqueue and callbacks carry one flow id, so that issue and
+completion pair up on one device
+(``bench/tests/data/smla4-mp16-x4-tiny.*``).  On one chip the same
+events carry ordinal 0 (``smla4-mp16-tiny.*``, a TPU v5e), so one
+chip is reduced the same way.  ``extract`` builds each device's
+executions from its own events alone; an execution event that names no
+device of the run is an error.  ``reduce`` gives:
 
 * ``window_s``: from the start of the first ``bench.job`` span to the end
   of the last;
 * ``busy_s``: per device, the length of the union of its execution
   intervals inside the window;
 * ``device_ops``: the programs that took most execution time;
-* ``idle_gaps``: the longest stretches inside the window in which the
-  chip held no execution, each named by the innermost harness span that
-  was open at its middle (what the host was doing).
+* ``idle_gaps``: the longest stretches inside the window in which a
+  device held no execution, over every device, each named by the
+  innermost harness span that was open at its middle (what the host was
+  doing).
 """
 from __future__ import annotations
 
@@ -41,6 +59,11 @@ PROFILE_MODE = "TRACE_ONLY_HOST"
 EXEC_START = "tpu::System::Execute=>IssueSequencedEvent"
 EXEC_END = "ReadSyncFlag"
 EXEC_NAME = "program execution"
+#: the runtime's events that name the device of an issue (the enqueue
+#: inside it) and of a completion (the callbacks after it), by this stat
+ENQUEUE_DEVICE = "DoEnqueueProgram"
+END_DEVICE = "CompleteCallbacks"
+ORDINAL = "device_ordinal"
 SPAN_PREFIX = "bench."
 TOP = 10
 
@@ -87,28 +110,66 @@ def executions(starts: list[float], ends: list[float]) -> list[list]:
     return out
 
 
+def _ordinal(event) -> int | None:
+    for k, v in event.stats:
+        if k == ORDINAL:
+            return int(v)
+    return None
+
+
+def _device_of(events: list, i: int) -> int | None:
+    """The device of execution event ``events[i]`` on a host line sorted
+    by start, parents first: an issue is its enqueue's device, a
+    completion-flag read that of the callbacks that follow it on the
+    line, before the next read."""
+    e = events[i]
+    if e.name == EXEC_START:
+        end = e.start_ns + e.duration_ns
+        for f in events[i + 1:]:
+            if f.start_ns > end:
+                break
+            if f.name == ENQUEUE_DEVICE:
+                return _ordinal(f)
+    else:
+        for f in events[i + 1:]:
+            if f.name == EXEC_END:
+                break
+            if f.name == END_DEVICE:
+                return _ordinal(f)
+    return None
+
+
 def extract(path: str, n_devices: int) -> dict:
-    """``{"devices": [[[name, start_ns, dur_ns], ...]], "spans": [[name,
-    start_ns, dur_ns], ...]}`` from one ``.xplane.pb`` of a run on
-    `n_devices` chips."""
-    if n_devices != 1:
-        raise ValueError(f"the runtime's events name no device: a trace "
-                         f"of {n_devices} chips cannot be reduced")
+    """``{"devices": [[[name, start_ns, dur_ns], ...], ...], "spans":
+    [[name, start_ns, dur_ns], ...]}`` from one ``.xplane.pb`` of a run
+    on `n_devices` chips: one execution list per device, each built by
+    `executions` from that device's events alone."""
     import jax
     pd = jax.profiler.ProfileData.from_file(path)
-    spans, starts, ends = [], [], []
+    spans = []
+    starts = [[] for _ in range(n_devices)]
+    ends = [[] for _ in range(n_devices)]
     for plane in pd.planes:
         if not plane.name.startswith("/host:"):
             continue
         for ln in plane.lines:
-            for e in ln.events:
+            events = sorted(ln.events,
+                            key=lambda e: (e.start_ns, -e.duration_ns))
+            for i, e in enumerate(events):
                 if e.name.startswith(SPAN_PREFIX):
                     spans.append([e.name, e.start_ns, e.duration_ns])
-                elif e.name == EXEC_START:
-                    starts.append(e.start_ns)
-                elif e.name == EXEC_END:
-                    ends.append(e.start_ns + e.duration_ns)
-    return {"devices": [executions(starts, ends)], "spans": spans}
+                if e.name not in (EXEC_START, EXEC_END):
+                    continue
+                dev = _device_of(events, i)
+                if dev is None or not 0 <= dev < n_devices:
+                    raise ValueError(f"{e.name} at {e.start_ns} ns names no "
+                                     f"device of {n_devices} (got {dev})")
+                if e.name == EXEC_START:
+                    starts[dev].append(e.start_ns)
+                else:
+                    ends[dev].append(e.start_ns + e.duration_ns)
+    return {"devices": [executions(s, e) for s, e in zip(starts, ends)],
+            "spans": spans}
 
 
 def _union(intervals: list[tuple[float, float]]) -> list[list[float]]:

@@ -22,9 +22,14 @@ from bench.lib import gen, program
 
 @dataclasses.dataclass
 class Bucket:
-    """One finished bucket of a job."""
+    """One finished bucket of a job, and how the job laid it out:
+    its rows split in contiguous blocks over `devices` chips and stepped
+    by `loops` while-loops, each until its own rows are done
+    (``program.loop_layout``)."""
     job: int
     meta: dict               # the program's record of the bucket
+    devices: int = 1
+    loops: int = 1
 
 
 @dataclasses.dataclass
@@ -75,7 +80,8 @@ def run(make_job: Callable[[int], gen.Job], config: dict, traffic: dict,
             with jax.profiler.TraceAnnotation("bench.run_sweep"):
                 res = program.run(g)
         jobs.append(JobRun(job, g, res))
-        buckets += [Bucket(k, meta) for meta in res.buckets]
+        layout = program.loop_layout(g)
+        buckets += [Bucket(k, meta, *layout) for meta in res.buckets]
         k += 1
     compiles = program.compile_count() - c0
     return Window(t0, seconds, jobs, buckets, compiles, time.perf_counter())

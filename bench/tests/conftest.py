@@ -36,8 +36,7 @@ def own_compile_cache(tmp_path_factory):
     mp.undo()
 
 
-@pytest.fixture
-def tiny(monkeypatch):
+def patch_tiny(monkeypatch) -> None:
     """Every traffic file the harness reads comes back at TINY size."""
     from bench.lib import registry
     real = registry.traffic
@@ -46,8 +45,7 @@ def tiny(monkeypatch):
                         tiny_traffic(real(name, bench)))
 
 
-@pytest.fixture
-def one_job(monkeypatch):
+def patch_one_job(monkeypatch) -> None:
     """The window closes as soon as its first job has returned, so that
     every bucket of that job counts and no second job starts."""
     from bench.lib import program, window
@@ -62,6 +60,16 @@ def one_job(monkeypatch):
         return res
     monkeypatch.setattr(window, "time", clock)
     monkeypatch.setattr(window.program, "run", run_then_close)
+
+
+@pytest.fixture
+def tiny(monkeypatch):
+    patch_tiny(monkeypatch)
+
+
+@pytest.fixture
+def one_job(monkeypatch):
+    patch_one_job(monkeypatch)
 
 
 def run_cell(name: str, seed: int = 2**31 + 7) -> dict:
